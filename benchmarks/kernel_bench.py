@@ -86,7 +86,7 @@ def run() -> list[dict]:
     assign = jnp.asarray(rng.integers(0, v2, (s_, b2)), jnp.int32)
     remw = jnp.asarray(rng.uniform(0.0, 400.0, (s_, b2)), jnp.float32)
     ref_fn = jax.jit(lambda a, w: mc_vm_stats_ref(a, w, v2))
-    pal_fn = lambda a, w: mc_vm_stats(a, w, v=v2, interpret=True)
+    pal_fn = lambda a, w: mc_vm_stats(a, w, v=v2)
     rows.append({"table": "kernels", "kernel": "mc_vm_reduce",
                  "shape": f"S{s_} B{b2} V{v2}",
                  "ref_us": round(_time(ref_fn, assign, remw)),
@@ -106,11 +106,10 @@ def run() -> list[dict]:
         dst = jnp.asarray(rng.integers(0, v_, (pop, k_)), jnp.int32)
         cand = apply_moves(al, t_idx, dst).reshape(pop * k_, b_)
         full_fn = lambda c: population_fitness(
-            c, e, rm, cores, mem, price, spot, **kw, interpret=True)[0]
-        base = population_reduce(al, e, rm, interpret=True)
+            c, e, rm, cores, mem, price, spot, **kw)[0]
+        base = population_reduce(al, e, rm)
         delta_fn = lambda t: delta_fitness(
-            al, t, dst, base, e, rm, cores, mem, price, spot, **kw,
-            interpret=True)[0]
+            al, t, dst, base, e, rm, cores, mem, price, spot, **kw)[0]
         full_us = _time(full_fn, cand)
         delta_us = _time(delta_fn, t_idx)
         rows.append({"table": "kernels", "kernel": "sched_fitness_delta",

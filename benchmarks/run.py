@@ -20,6 +20,8 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro import compile_cache  # noqa: E402
+
 
 class BenchError(RuntimeError):
     pass
@@ -183,6 +185,7 @@ def dynamic_rollup(sim_rows: list[dict], smoke: bool,
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
                     help="J60-only Table VI and smaller ILS bench")

@@ -18,7 +18,7 @@ import tempfile
 
 sys.path.insert(0, "src")
 
-from repro import api
+from repro import api, compile_cache
 from repro.core.ils import ILSParams
 from repro.core.ils_jax import BatchedILSParams
 from repro.sim import (CorrelatedShockProcess, MarkovModulatedProcess,
@@ -28,6 +28,7 @@ from repro.sim.mc_engine import MCParams
 
 
 def main() -> None:
+    compile_cache.enable()
     job_name = sys.argv[1] if len(sys.argv) > 1 else "J60"
     s = int(sys.argv[2]) if len(sys.argv) > 2 else 256
     job = make_job(job_name)

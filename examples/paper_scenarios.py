@@ -10,12 +10,13 @@ import sys
 
 sys.path.insert(0, "src")
 
-from repro import api
+from repro import api, compile_cache
 from repro.core.ils import ILSParams
 from repro.sim.mc_engine import MCParams
 
 
 def main() -> None:
+    compile_cache.enable()
     job = sys.argv[1] if len(sys.argv) > 1 else "J60"
     n = int(sys.argv[2]) if len(sys.argv) > 2 else 256
     mc = MCParams(n_scenarios=n, dt=30.0, seed=3)

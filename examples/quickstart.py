@@ -15,7 +15,7 @@ import sys
 
 sys.path.insert(0, "src")
 
-from repro import api
+from repro import api, compile_cache
 from repro.core import (CloudConfig, ILSParams, burst_allocation,
                         compute_dspot, evaluate, run_ils)
 from repro.sim.mc_engine import MCParams
@@ -23,6 +23,7 @@ from repro.sim.workloads import make_job
 
 
 def main() -> None:
+    compile_cache.enable()
     job_name = sys.argv[1] if len(sys.argv) > 1 else "J60"
     cfg = CloudConfig()
     job = make_job(job_name)

@@ -16,6 +16,7 @@ sys.path.insert(0, "src")
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.configs import get_config
 from repro.core.dynamic import BURST_HADS, build_primary_map
 from repro.core.ils import ILSParams
@@ -28,6 +29,7 @@ from repro.train.steps import make_serve_step
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--batches", type=int, default=6)
     ap.add_argument("--arch", default="rwkv6-7b")

@@ -18,6 +18,7 @@ sys.path.insert(0, "src")
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.cluster.runtime import TraceExecutor, TrainTaskPayload
 from repro.configs import get_config
 from repro.core.dynamic import BURST_HADS, build_primary_map
@@ -32,6 +33,7 @@ from repro.train.steps import make_train_step
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--tasks", type=int, default=4)
     ap.add_argument("--steps", type=int, default=24)

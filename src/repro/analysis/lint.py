@@ -55,7 +55,7 @@ _NP_SYNC_FUNCS = {"asarray", "array", "frombuffer", "copyto"}
 
 _HASHABLE_NAMES = {
     "int", "str", "bool", "float", "bytes", "tuple", "frozenset",
-    "None", "NoneType", "type", "Callable", "callable",
+    "None", "NoneType", "type", "Callable", "callable", "Mesh",
 }
 
 

@@ -51,7 +51,6 @@ class BatchedILSParams:
     swap_tasks: int = 4        # tasks relocated per candidate
     alpha: float = 0.5
     seed: int = 0
-    interpret: bool = True     # Pallas interpret mode (CPU container)
     engine: str = "scan"       # "scan" (fused delta path) | "step" (full)
 
 
@@ -75,10 +74,10 @@ def _propose(key, p: int, b: int, k: int, n: int, active_uids):
     return t_idx, active_uids[d_pos]
 
 
-@functools.partial(jax.jit, static_argnames=("k", "n", "interpret"))
+@functools.partial(jax.jit, static_argnames=("k", "n"))
 def _ils_step(alloc, best_fit, key, active_uids, e, rm, cores, mem, price,
               spot, *, k: int, n: int, dspot, deadline, alpha, scale,
-              boot_s, interpret: bool):
+              boot_s):
     """One batched iteration, full path: materialise + re-reduce P·K."""
     p, b = alloc.shape
     t_idx, dest = _propose(key, p, b, k, n, active_uids)
@@ -87,7 +86,7 @@ def _ils_step(alloc, best_fit, key, active_uids, e, rm, cores, mem, price,
     fit, _, _ = population_fitness(
         cand.reshape(p * k, b), e, rm, cores, mem, price, spot,
         dspot=dspot, deadline=deadline, alpha=alpha, cost_scale=scale,
-        boot_s=boot_s, interpret=interpret)
+        boot_s=boot_s)
     fit = fit.reshape(p, k)
     j = jnp.argmin(fit, axis=1)
     best_cand_fit = jnp.take_along_axis(fit, j[:, None], axis=1)[:, 0]
@@ -102,7 +101,7 @@ def _ils_step(alloc, best_fit, key, active_uids, e, rm, cores, mem, price,
 
 def _ils_scan_impl(alloc, best_fit, keys, active_uids, e, rm, cores, mem,
                    price, spot, *, k: int, n: int, dspot, deadline, alpha,
-                   scale, boot_s, interpret: bool):
+                   scale, boot_s):
     """The whole search as one fused scan; returns (alloc, fit, history)."""
     p, b = alloc.shape
     rows = jnp.arange(p)
@@ -113,7 +112,7 @@ def _ils_scan_impl(alloc, best_fit, keys, active_uids, e, rm, cores, mem,
         fit, _, _ = delta_fitness(
             alloc, t_idx, dest, base, e, rm, cores, mem, price, spot,
             dspot=dspot, deadline=deadline, alpha=alpha, cost_scale=scale,
-            boot_s=boot_s, interpret=interpret)
+            boot_s=boot_s)
         j = jnp.argmin(fit, axis=1)
         cand_fit = jnp.take_along_axis(fit, j[:, None], axis=1)[:, 0]
         improved = cand_fit < best_fit
@@ -125,10 +124,10 @@ def _ils_scan_impl(alloc, best_fit, keys, active_uids, e, rm, cores, mem,
         alloc = alloc.at[rows[:, None], ct].set(
             jnp.where(improved[:, None], cd[:, None], cur))
         best_fit = jnp.where(improved, cand_fit, best_fit)
-        base = population_reduce(alloc, e, rm, interpret=interpret)
+        base = population_reduce(alloc, e, rm)
         return (alloc, best_fit, base), jnp.min(best_fit)
 
-    base0 = population_reduce(alloc, e, rm, interpret=interpret)
+    base0 = population_reduce(alloc, e, rm)
     (alloc, best_fit, _), hist = jax.lax.scan(
         step, (alloc, best_fit, base0), keys)
     return alloc, best_fit, hist
@@ -140,7 +139,7 @@ def _ils_scan(donate: bool):
     accelerators.  The backend query happens at first call, not import —
     donation is a no-op (plus a warning) on CPU, and callers may still be
     configuring platforms at import time."""
-    return jax.jit(_ils_scan_impl, static_argnames=("k", "n", "interpret"),
+    return jax.jit(_ils_scan_impl, static_argnames=("k", "n"),
                    donate_argnums=(0, 1) if donate else ())
 
 
@@ -184,11 +183,11 @@ def run_batched_ils(tasks: Sequence[TaskSpec], pool: list[VMInstance],
 
     kw = dict(k=params.proposals, n=params.swap_tasks, dspot=dspot,
               deadline=deadline, alpha=params.alpha, scale=scale,
-              boot_s=cfg.boot_overhead_s, interpret=params.interpret)
+              boot_s=cfg.boot_overhead_s)
     fit0, _, _ = population_fitness(
         alloc, e, rm, cores, mem, price, spot, dspot=dspot,
         deadline=deadline, alpha=params.alpha, cost_scale=scale,
-        boot_s=cfg.boot_overhead_s, interpret=params.interpret)
+        boot_s=cfg.boot_overhead_s)
 
     # per-iteration keys, derived identically for both engines
     key = jax.random.PRNGKey(params.seed)

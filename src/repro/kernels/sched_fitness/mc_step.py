@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .sched_fitness import _pad_vms
+from .sched_fitness import _pad_vms, resolve_interpret
 
 
 def _mc_kernel(cols_ref, w_ref, load_ref, cnt_ref, maxw_ref):
@@ -58,7 +58,7 @@ def _mc_kernel(cols_ref, w_ref, load_ref, cnt_ref, maxw_ref):
 
 @functools.partial(jax.jit, static_argnames=("v", "sb", "tb", "interpret"))
 def mc_vm_reduce(cols: jax.Array, w: jax.Array, v: int, *, sb: int = 8,
-                 tb: int = 128, interpret: bool = False):
+                 tb: int = 128, interpret: bool | None = None):
     """cols int32 [S, B] (VM column per task, anything outside [0, v) is
     ignored); w f32 [S, B] (per-task weight, e.g. remaining base work) ->
     (load, cnt, maxw) each f32 [S, v]."""
@@ -81,7 +81,7 @@ def mc_vm_reduce(cols: jax.Array, w: jax.Array, v: int, *, sb: int = 8,
                   pl.BlockSpec((sb, tb), lambda i, j: (i, j))],
         out_specs=[out_spec] * 3,
         out_shape=[jax.ShapeDtypeStruct((s_pad, v_pad), jnp.float32)] * 3,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(cols, w)
     return load[:s, :v], cnt[:s, :v], maxw[:s, :v]
 
@@ -118,7 +118,7 @@ def _span_kernel(m_ref, cols_ref, rem_ref, drem_ref,
 @functools.partial(jax.jit, static_argnames=("v", "sb", "tb", "interpret"))
 def mc_span_reduce(cols: jax.Array, rem: jax.Array, drem: jax.Array,
                    m: jax.Array, v: int, *, sb: int = 8, tb: int = 128,
-                   interpret: bool = False):
+                   interpret: bool | None = None):
     """Fused event-horizon span advance + VM reductions (DESIGN.md §2.5).
 
     cols int32 [S, B] (VM column per task; anything outside [0, v) is
@@ -155,6 +155,6 @@ def mc_span_reduce(cols: jax.Array, rem: jax.Array, drem: jax.Array,
                    jax.ShapeDtypeStruct((s_pad, v_pad), jnp.float32),
                    jax.ShapeDtypeStruct((s_pad, v_pad), jnp.float32),
                    jax.ShapeDtypeStruct((s_pad, v_pad), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(m_col, cols, rem, drem)
     return (rem_new[:s, :b], load[:s, :v], cnt[:s, :v], maxw[:s, :v])

@@ -15,18 +15,14 @@ from .mc_step import mc_span_reduce, mc_vm_reduce
 from .sched_fitness import delta_population_fitness, population_reduce
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@jax.jit
 def population_fitness(alloc, e, rm, vm_cores, vm_mem, vm_price, vm_is_spot,
-                       *, dspot, deadline, alpha, cost_scale, boot_s,
-                       interpret: bool = True):
+                       *, dspot, deadline, alpha, cost_scale, boot_s):
     """Fitness of P candidate schedules (Eq. 8, LPT makespan bound).
 
-    ``interpret=True`` executes the Pallas body in Python — the CPU
-    validation mode; on TPU pass ``interpret=False``.
     Returns (fitness [P], cost [P], makespan [P]).
     """
-    loads, maxe, cnt, maxmem = population_reduce(alloc, e, rm,
-                                                 interpret=interpret)
+    loads, maxe, cnt, maxmem = population_reduce(alloc, e, rm)
     busy = cnt > 0
     makespan = jnp.where(
         busy, jnp.maximum(loads / vm_cores[None], maxe) + boot_s, 0.0)
@@ -42,10 +38,10 @@ def population_fitness(alloc, e, rm, vm_cores, vm_mem, vm_price, vm_is_spot,
     return jnp.where(bad, jnp.inf, fit), cost, mkp
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@jax.jit
 def delta_fitness(alloc, t_idx, dest, base, e, rm, vm_cores, vm_mem,
                   vm_price, vm_is_spot, *, dspot, deadline, alpha,
-                  cost_scale, boot_s, interpret: bool = True):
+                  cost_scale, boot_s):
     """Fitness of P·K candidate moves, evaluated incrementally (Eq. 8).
 
     ``alloc`` [P, B] is the incumbent; candidate (p, k) relocates tasks
@@ -60,13 +56,13 @@ def delta_fitness(alloc, t_idx, dest, base, e, rm, vm_cores, vm_mem,
                         for x in (alpha, cost_scale, boot_s, deadline)])
     return delta_population_fitness(alloc, t_idx, dest, base, e, rm,
                                     vm_cores, vm_mem, vm_price, limit,
-                                    params, interpret=interpret)
+                                    params)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@jax.jit
 def insert_tasks(alloc, dest, base, e, rm, e_new, rm_new, vm_cores, vm_mem,
                  vm_price, vm_is_spot, *, dspot, deadline, alpha,
-                 cost_scale, boot_s, interpret: bool = True):
+                 cost_scale, boot_s):
     """Score single-task insertions without re-reducing untouched columns.
 
     The service layer's admission fast path (DESIGN.md §2.9): candidate
@@ -113,11 +109,11 @@ def insert_tasks(alloc, dest, base, e, rm, e_new, rm_new, vm_cores, vm_mem,
         jnp.concatenate([vm_is_spot,
                          jnp.zeros(1, jnp.asarray(vm_is_spot).dtype)]),
         dspot=dspot, deadline=deadline, alpha=alpha, cost_scale=cost_scale,
-        boot_s=boot_s, interpret=interpret)
+        boot_s=boot_s)
 
 
-@functools.partial(jax.jit, static_argnames=("v", "interpret"))
-def mc_vm_stats(assign, rem, *, v: int, interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("v",))
+def mc_vm_stats(assign, rem, *, v: int):
     """Per-scenario per-VM remaining-load / unfinished-count / max-remaining,
     one streamed pass over the [S, B] assignment (the Monte-Carlo engine's
     hot per-slot reduction, DESIGN.md §2.3).  Tasks with ``rem <= 0`` or an
@@ -126,11 +122,11 @@ def mc_vm_stats(assign, rem, *, v: int, interpret: bool = True):
     pending = rem > 0.0
     cols = jnp.where(pending, assign, -1)
     w = jnp.where(pending, rem, 0.0).astype(jnp.float32)
-    return mc_vm_reduce(cols, w, v, interpret=interpret)
+    return mc_vm_reduce(cols, w, v)
 
 
-@functools.partial(jax.jit, static_argnames=("v", "interpret"))
-def mc_span_advance(assign, rem, drem, m, *, v: int, interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("v",))
+def mc_span_advance(assign, rem, drem, m, *, v: int):
     """Event-horizon span advance fused with the VM reductions
     (DESIGN.md §2.5): jump ``m`` uniform slots in closed form
     (``rem_new = max(rem − m·drem, 0)``, exact — the engine only requests
@@ -140,5 +136,4 @@ def mc_span_advance(assign, rem, drem, m, *, v: int, interpret: bool = True):
     f32 [S, v])."""
     pending = rem > 0.0
     cols = jnp.where(pending, assign, -1)
-    return mc_span_reduce(cols, rem, jnp.where(pending, drem, 0.0), m, v,
-                          interpret=interpret)
+    return mc_span_reduce(cols, rem, jnp.where(pending, drem, 0.0), m, v)

@@ -11,6 +11,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+#: the oracles reduce in full f32 on every backend — a TPU's default dot
+#: precision would round the weights to bf16
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def population_reduce_ref(alloc: jax.Array, e: jax.Array, rm: jax.Array
                           ) -> tuple[jax.Array, jax.Array, jax.Array,
@@ -19,7 +23,7 @@ def population_reduce_ref(alloc: jax.Array, e: jax.Array, rm: jax.Array
     p, b = alloc.shape
     v = e.shape[1]
     onehot = jax.nn.one_hot(alloc, v, dtype=e.dtype)         # [P, B, V]
-    loads = jnp.einsum("pbv,bv->pv", onehot, e)
+    loads = jnp.einsum("pbv,bv->pv", onehot, e, precision=HIGHEST)
     cnt = onehot.sum(axis=1)
     maxe = jnp.max(jnp.where(onehot > 0, e[None], 0.0), axis=1)
     maxmem = jnp.max(jnp.where(onehot > 0, rm[None, :, None], 0.0), axis=1)
@@ -69,7 +73,7 @@ def mc_vm_stats_ref(cols: jax.Array, w: jax.Array, v: int
     Returns (load, cnt, maxw) each f32 [S, v]."""
     keep = (cols >= 0) & (cols < v)
     onehot = jax.nn.one_hot(jnp.where(keep, cols, v), v, dtype=w.dtype)
-    load = jnp.einsum("sbv,sb->sv", onehot, w)
+    load = jnp.einsum("sbv,sb->sv", onehot, w, precision=HIGHEST)
     cnt = onehot.sum(axis=1)
     maxw = jnp.max(onehot * w[:, :, None], axis=1)
     return load, cnt, maxw

@@ -18,8 +18,9 @@ once-per-iteration base reductions, and finalises Eq. 8 in-kernel.  Work per
 candidate drops from O(B·V) to O(C·B + V); candidate allocation vectors
 ([P, K, B]) are never built — the path's footprint is the gathered
 e-columns tensor, O(P·K·C·B) f32, traded for the V-fold compute win.
-Grid: (P / pb, B / tb); per-chain the K proposals ride in the block's
-second dimension.
+Grid: (P, B / tb); a chain's C·K (touched column, proposal) pairs ride
+as the rows of 2-D [C·K, tb] tiles, so Mosaic never splits the sublane
+dimension.
 """
 from __future__ import annotations
 
@@ -31,6 +32,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128   # TPU vector lane width; V is padded to this
+
+
+def resolve_interpret(interpret: bool | None = None) -> bool:
+    """The one rule for Pallas interpret mode: interpret exactly when the
+    default backend is the CPU.  An explicit bool wins — a test compiling
+    for a described TPU from a CPU process passes ``False``."""
+    return jax.default_backend() == "cpu" if interpret is None else interpret
 
 
 def _pad_vms(v: int) -> int:
@@ -68,7 +76,7 @@ def _kernel(alloc_ref, e_ref, rm_ref, loads_ref, maxe_ref, cnt_ref,
 @functools.partial(jax.jit, static_argnames=("pb", "tb", "interpret"))
 def population_reduce(alloc: jax.Array, e: jax.Array, rm: jax.Array,
                       *, pb: int = 8, tb: int = 128,
-                      interpret: bool = False):
+                      interpret: bool | None = None):
     """alloc int32 [P, B]; e f32 [B, V]; rm f32 [B] ->
     (loads, maxe, cnt, maxmem) each f32 [P, V]."""
     p, b = alloc.shape
@@ -95,26 +103,31 @@ def population_reduce(alloc: jax.Array, e: jax.Array, rm: jax.Array,
                   pl.BlockSpec((tb, 1), lambda i, j: (j, 0))],
         out_specs=[out_spec] * 4,
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(alloc, e, rm)
     return (loads[:p, :v], maxe[:p, :v], cnt[:p, :v], maxmem[:p, :v])
 
 
-def _delta_kernel(alloc_ref, ecols_ref, rm_ref, m_ref, cols_ref,
+def _delta_kernel(alloc_ref, ecols_ref, info_ref, rm_ref,
                   bl_ref, bx_ref, bc_ref, bm_ref,
                   cores_ref, mem_ref, price_ref, limit_ref, par_ref,
                   fit_ref, cost_ref, mkp_ref,
                   sl, sx, sc, sm):
-    """Incremental candidate scoring for a tile of pb population chains.
+    """Incremental candidate scoring for one population chain.
 
-    Streams task tiles (sequential grid dim 1) and re-reduces only the
-    C = n + 1 columns each candidate touches into [pb, Kp, C] scratch; the
-    last tile splices them into each chain's base [V] rows and finalises
-    Eq. 8.
+    Rows are (touched column slot c, candidate k) pairs, slot-major
+    (``r = c·Kp + k``), so every operand is a 2-D tile with tasks on the
+    lanes.  Streams task tiles (sequential grid dim 1) and re-reduces each
+    row's column into [R, 1] scratch; the last tile splices the C slots
+    into the chain's base [V] row per candidate and finalises Eq. 8.
+    ``info`` lanes: 0 = column id, 1 = is-destination flag, 2.. = the
+    candidate's moved task ids.
     """
     j = pl.program_id(1)
-    pb, kp, c = cols_ref.shape
     tb = alloc_ref.shape[1]
+    kp = fit_ref.shape[0]
+    c = ecols_ref.shape[0] // kp
+    n = info_ref.shape[1] - 2
 
     @pl.when(j == 0)
     def _init():
@@ -123,66 +136,69 @@ def _delta_kernel(alloc_ref, ecols_ref, rm_ref, m_ref, cols_ref,
         sc[...] = jnp.zeros_like(sc)
         sm[...] = jnp.zeros_like(sm)
 
-    alloc = alloc_ref[...]                        # [pb, tb] int32
-    ecols = ecols_ref[...].reshape(pb, kp, c, tb)  # e[t, cols[p, k, c]]
+    alloc = alloc_ref[...]                        # [1, tb] int32
+    ecols = ecols_ref[...]                        # [R, tb] e[t, col(r)]
+    info = info_ref[...]                          # [R, 2 + n] int32
     rm = rm_ref[...]                              # [1, tb]
-    m = m_ref[...]                                # [pb, Kp, n] moved tasks
-    cols = cols_ref[...]                          # [pb, Kp, C]; C-1 = dest
 
-    # new occupancy of column cols[p, k, c] under candidate (p, k), this
-    # task tile: a task sits there iff it stayed (assigned and not moved)
-    # or the column is the destination and the task was moved there.
-    t_glob = j * tb + jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, tb), 3)
-    moved = jnp.any(m[..., None] == t_glob, axis=2)           # [pb, Kp, tb]
-    is_dest = (cols == cols[:, :, c - 1:c])[..., None]        # [pb,Kp,C,1]
-    stay = (alloc[:, None, None, :] == cols[..., None]) & ~moved[:, :, None]
-    on = (stay | (moved[:, :, None] & is_dest)).astype(ecols.dtype)
+    # new occupancy of column col(r) under candidate k(r), this task
+    # tile: a task sits there iff it stayed (assigned and not moved) or
+    # the column is the destination and the task was moved there.
+    t_glob = j * tb + jax.lax.broadcasted_iota(jnp.int32, (1, tb), 1)
+    moved = info[:, 2:3] == t_glob                            # [R, tb]
+    for i in range(1, n):
+        moved = moved | (info[:, 2 + i:3 + i] == t_glob)
+    stay = (alloc == info[:, 0:1]) & jnp.logical_not(moved)
+    on = stay | (moved & (info[:, 1:2] > 0))
+    w = jnp.where(on, ecols, 0.0)
 
-    sl[...] += jnp.sum(on * ecols, axis=3)
-    sc[...] += jnp.sum(on, axis=3)
-    sx[...] = jnp.maximum(sx[...], jnp.max(on * ecols, axis=3))
-    sm[...] = jnp.maximum(sm[...], jnp.max(on * rm[None, None], axis=3))
+    sl[...] += jnp.sum(w, axis=1, keepdims=True)
+    sc[...] += jnp.sum(jnp.where(on, 1.0, 0.0), axis=1, keepdims=True)
+    sx[...] = jnp.maximum(sx[...], jnp.max(w, axis=1, keepdims=True))
+    sm[...] = jnp.maximum(sm[...], jnp.max(jnp.where(on, rm, 0.0), axis=1,
+                                           keepdims=True))
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finalise():
         vp = bl_ref.shape[1]
-        viota = jax.lax.broadcasted_iota(jnp.int32, (pb, kp, vp), 2)
-        rows_l = jnp.broadcast_to(bl_ref[...][:, None], (pb, kp, vp))
-        rows_x = jnp.broadcast_to(bx_ref[...][:, None], (pb, kp, vp))
-        rows_c = jnp.broadcast_to(bc_ref[...][:, None], (pb, kp, vp))
-        rows_m = jnp.broadcast_to(bm_ref[...][:, None], (pb, kp, vp))
-        slv, sxv, scv, smv = sl[...], sx[...], sc[...], sm[...]
+        viota = jax.lax.broadcasted_iota(jnp.int32, (kp, vp), 1)
+        rows_l = jnp.broadcast_to(bl_ref[...], (kp, vp))
+        rows_x = jnp.broadcast_to(bx_ref[...], (kp, vp))
+        rows_c = jnp.broadcast_to(bc_ref[...], (kp, vp))
+        rows_m = jnp.broadcast_to(bm_ref[...], (kp, vp))
         for i in range(c):    # splice the C re-reduced columns (duplicates
-            msk = viota == cols[:, :, i][..., None]  # carry equal values)
-            rows_l = jnp.where(msk, slv[:, :, i][..., None], rows_l)
-            rows_x = jnp.where(msk, sxv[:, :, i][..., None], rows_x)
-            rows_c = jnp.where(msk, scv[:, :, i][..., None], rows_c)
-            rows_m = jnp.where(msk, smv[:, :, i][..., None], rows_m)
+            lo = i * kp       # carry equal values)
+            msk = viota == info_ref[lo:lo + kp, 0:1]
+            rows_l = jnp.where(msk, sl[lo:lo + kp, :], rows_l)
+            rows_x = jnp.where(msk, sx[lo:lo + kp, :], rows_x)
+            rows_c = jnp.where(msk, sc[lo:lo + kp, :], rows_c)
+            rows_m = jnp.where(msk, sm[lo:lo + kp, :], rows_m)
 
-        alpha, scale = par_ref[0, 0], par_ref[0, 1]
-        boot_s, deadline = par_ref[0, 2], par_ref[0, 3]
-        cores = cores_ref[...][None]               # [1, 1, vp]
-        busy = rows_c > 0
-        mksp = jnp.where(
-            busy, jnp.maximum(rows_l / cores, rows_x) + boot_s, 0.0)
+        par = par_ref[...]                         # [1, LANE]
+        alpha, scale = par[:, 0:1], par[:, 1:2]
+        boot_s, deadline = par[:, 2:3], par[:, 3:4]
+        cores = cores_ref[...]                     # [1, vp]
+        mksp = jnp.where(rows_c > 0,
+                         jnp.maximum(rows_l / cores, rows_x) + boot_s, 0.0)
         mem_peak = rows_m * jnp.minimum(rows_c, cores)
-        mem_bad = jnp.any(mem_peak > mem_ref[...][None] + 1e-6, axis=2)
-        time_bad = jnp.any(mksp > limit_ref[...][None] + 1e-6, axis=2)
-        cost = jnp.sum(price_ref[...][None] * jnp.maximum(mksp - boot_s,
-                                                          0.0), axis=2)
-        mkp = jnp.max(mksp, axis=2)
+        bad = jnp.where((mem_peak > mem_ref[...] + 1e-6) |
+                        (mksp > limit_ref[...] + 1e-6), 1.0, 0.0)
+        cost = jnp.sum(price_ref[...] * jnp.maximum(mksp - boot_s, 0.0),
+                       axis=1, keepdims=True)
+        mkp = jnp.max(mksp, axis=1, keepdims=True)
         fit = alpha * cost / scale + (1 - alpha) * mkp / deadline
-        fit_ref[...] = jnp.where(mem_bad | time_bad, jnp.inf, fit)
+        fit_ref[...] = jnp.where(jnp.max(bad, axis=1, keepdims=True) > 0,
+                                 jnp.inf, fit)
         cost_ref[...] = cost
         mkp_ref[...] = mkp
 
 
-@functools.partial(jax.jit, static_argnames=("pb", "tb", "interpret"))
+@functools.partial(jax.jit, static_argnames=("tb", "interpret"))
 def delta_population_fitness(alloc: jax.Array, t_idx: jax.Array,
                              dest: jax.Array, base, e: jax.Array,
                              rm: jax.Array, vm_cores, vm_mem, vm_price,
-                             limit, params, *, pb: int = 8, tb: int = 128,
-                             interpret: bool = False):
+                             limit, params, *, tb: int = 128,
+                             interpret: bool | None = None):
     """Score P·K candidate moves incrementally against base reductions.
 
     alloc int32 [P, B]; t_idx int32 [P, K, n] (task ids relocated per
@@ -190,6 +206,7 @@ def delta_population_fitness(alloc: jax.Array, t_idx: jax.Array,
     f32 [P, V] for ``alloc`` (from ``population_reduce``); limit f32 [V] is
     the per-VM finish deadline; params f32 [4] = (alpha, cost_scale, boot_s,
     deadline).  Returns (fitness, cost, makespan) each f32 [P, K].
+    Grid: (P, B / tb) — one chain per program.
     """
     p, b = alloc.shape
     _, k, n = t_idx.shape
@@ -197,25 +214,33 @@ def delta_population_fitness(alloc: jax.Array, t_idx: jax.Array,
     c = n + 1
     v_pad = _pad_vms(v)
     b_pad = ((b + tb - 1) // tb) * tb
-    p_pad = ((p + pb - 1) // pb) * pb
     k_pad = ((k + 7) // 8) * 8
+    r = c * k_pad
 
     pi = jnp.arange(p)[:, None, None]
     src = alloc[pi, t_idx]                                    # [P, K, n]
     cols = jnp.concatenate([src, dest[:, :, None]], axis=2)   # [P, K, C]
-    pad_pk = ((0, p_pad - p), (0, k_pad - k), (0, 0))
-    cols = jnp.pad(cols, pad_pk,
+    pad_k = ((0, 0), (0, k_pad - k), (0, 0))
+    cols = jnp.pad(cols, pad_k,
                    constant_values=v_pad - 1)    # pad candidates -> pad VM
-    m = jnp.pad(t_idx, pad_pk, constant_values=b_pad - 1)
+    m = jnp.pad(t_idx, pad_k, constant_values=b_pad - 1)
+    # slot-major rows r = c·Kp + k: column id, is-destination, moved ids
+    is_dest = (cols == cols[:, :, c - 1:c]).astype(jnp.int32)
+    info = jnp.concatenate(
+        [cols.transpose(0, 2, 1).reshape(p, r, 1),
+         is_dest.transpose(0, 2, 1).reshape(p, r, 1),
+         jnp.broadcast_to(m[:, None], (p, c, k_pad, n)).reshape(p, r, n)],
+        axis=2)
 
     ep = jnp.pad(e.astype(jnp.float32), ((0, b_pad - b), (0, v_pad - v)))
-    ecols = ep.T[cols].reshape(p_pad, k_pad * c, b_pad)  # one O(PKCB) gather
-    alloc_p = jnp.pad(alloc, ((0, p_pad - p), (0, b_pad - b)),
-                      constant_values=v_pad - 1)
+    ecols = ep.T[info[:, :, 0]]                   # one O(PKCB) gather
+    alloc_p = jnp.pad(alloc, ((0, 0), (0, b_pad - b)),
+                      constant_values=v_pad - 1)[:, None]
     rm_p = jnp.pad(rm.astype(jnp.float32), (0, b_pad - b))[None]
 
-    pad_v = ((0, p_pad - p), (0, v_pad - v))
-    bl, bx, bc, bm = (jnp.pad(x.astype(jnp.float32), pad_v) for x in base)
+    pad_v = ((0, 0), (0, v_pad - v))
+    bl, bx, bc, bm = (jnp.pad(x.astype(jnp.float32), pad_v)[:, None]
+                      for x in base)
     cores = jnp.pad(vm_cores.astype(jnp.float32), (0, v_pad - v),
                     constant_values=1.0)[None]   # 1.0: keep pad cols /-safe
     memv = jnp.pad(vm_mem.astype(jnp.float32), (0, v_pad - v))[None]
@@ -224,28 +249,26 @@ def delta_population_fitness(alloc: jax.Array, t_idx: jax.Array,
     par = jnp.zeros((1, LANE), jnp.float32).at[0, :4].set(
         params.astype(jnp.float32))
 
-    grid = (p_pad // pb, b_pad // tb)
-    row_spec = pl.BlockSpec((pb, v_pad), lambda i, j: (i, 0))
+    grid = (p, b_pad // tb)
+    row_spec = pl.BlockSpec((None, 1, v_pad), lambda i, j: (i, 0, 0))
     vm_spec = pl.BlockSpec((1, v_pad), lambda i, j: (0, 0))
-    out_spec = pl.BlockSpec((pb, k_pad), lambda i, j: (i, 0))
+    out_spec = pl.BlockSpec((None, k_pad, 1), lambda i, j: (i, 0, 0))
     fit, cost, mkp = pl.pallas_call(
         _delta_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((pb, tb), lambda i, j: (i, j)),           # alloc
-            pl.BlockSpec((pb, k_pad * c, tb), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((1, tb), lambda i, j: (0, j)),            # rm
-            pl.BlockSpec((pb, k_pad, n), lambda i, j: (i, 0, 0)),  # moved
-            pl.BlockSpec((pb, k_pad, c), lambda i, j: (i, 0, 0)),  # cols
-            row_spec, row_spec, row_spec, row_spec,                # base
-            vm_spec, vm_spec, vm_spec, vm_spec,                    # vm data
-            pl.BlockSpec((1, LANE), lambda i, j: (0, 0)),          # params
+            pl.BlockSpec((None, 1, tb), lambda i, j: (i, 0, j)),     # alloc
+            pl.BlockSpec((None, r, tb), lambda i, j: (i, 0, j)),     # ecols
+            pl.BlockSpec((None, r, 2 + n), lambda i, j: (i, 0, 0)),  # info
+            pl.BlockSpec((1, tb), lambda i, j: (0, j)),              # rm
+            row_spec, row_spec, row_spec, row_spec,                  # base
+            vm_spec, vm_spec, vm_spec, vm_spec,                      # vm data
+            pl.BlockSpec((1, LANE), lambda i, j: (0, 0)),            # params
         ],
         out_specs=[out_spec] * 3,
-        out_shape=[jax.ShapeDtypeStruct((p_pad, k_pad), jnp.float32)] * 3,
-        scratch_shapes=[pltpu.VMEM((pb, k_pad, c), jnp.float32)
-                        for _ in range(4)],
-        interpret=interpret,
-    )(alloc_p, ecols, rm_p, m, cols, bl, bx, bc, bm,
+        out_shape=[jax.ShapeDtypeStruct((p, k_pad, 1), jnp.float32)] * 3,
+        scratch_shapes=[pltpu.VMEM((r, 1), jnp.float32) for _ in range(4)],
+        interpret=resolve_interpret(interpret),
+    )(alloc_p, ecols, info, rm_p, bl, bx, bc, bm,
       cores, memv, price, limit, par)
-    return fit[:p, :k], cost[:p, :k], mkp[:p, :k]
+    return fit[:, :k, 0], cost[:, :k, 0], mkp[:, :k, 0]

@@ -539,7 +539,7 @@ class Service:
         dl = max(float(a.deadline_s - t_b), self.mc.dt)
         scale = cost_scale(self._tasks, self.cfg) if self._tasks else 1.0
         base = population_reduce(alloc[None], jnp.asarray(e),
-                                 jnp.asarray(rm), interpret=True)
+                                 jnp.asarray(rm))
         fit, _, _ = insert_tasks(
             jnp.asarray(alloc[None]), jnp.asarray(dest[None]), base,
             jnp.asarray(e), jnp.asarray(rm), jnp.asarray(e_new),
@@ -549,7 +549,7 @@ class Service:
             jnp.asarray(self._price, jnp.float32),
             jnp.asarray(self._spot, jnp.float32),
             dspot=dl, deadline=dl, alpha=0.5, cost_scale=scale,
-            boot_s=self.cfg.boot_overhead_s, interpret=True)
+            boot_s=self.cfg.boot_overhead_s)
         fit = np.asarray(fit[0])
         if np.all(np.isinf(fit)):
             return int(feas[np.argmin(eta_ok[feas])])
@@ -686,7 +686,7 @@ class Service:
         params = BatchedILSParams(
             population=8, iterations=12, proposals=16,
             swap_tasks=min(4, len(not_started)),
-            seed=self.seed, interpret=True)
+            seed=self.seed)
         res = run_batched_ils(sub_tasks, self.pool, self.cfg, slack, slack,
                               params, market=self.policy.market,
                               initial=init if self.arrival.warm_start

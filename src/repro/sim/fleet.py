@@ -161,7 +161,8 @@ def slot_coverage(res, sl: slice) -> tuple[int, int]:
 
 def shard_events(ev: EventTensor, sharding) -> EventTensor:
     """Place an event tensor with its scenario axis split across devices;
-    the engine state (all ``[S, ...]``) follows by GSPMD propagation."""
+    the engine then runs each device's scenarios in a loop of its own
+    (``mc_engine._mc_run``)."""
     if sharding is None:
         return ev
     s3 = NamedSharding(sharding.mesh, PartitionSpec("s", None, None))

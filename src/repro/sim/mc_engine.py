@@ -84,6 +84,7 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.core.dynamic import (BURST_HADS, PolicyConfig, PrimaryPlan,
                                 build_primary_map)
@@ -97,6 +98,7 @@ from .events import SC_NONE, Scenario
 from .market import EventTensor, MarketProcess, as_process
 
 BIG = 1e30
+HIGHEST = jax.lax.Precision.HIGHEST
 
 #: VM column lifecycle codes (``vstate``)
 NOT_LAUNCHED, VM_ACTIVE, VM_HIBERNATED, VM_TERMINATED = 0, 1, 2, 3
@@ -148,7 +150,6 @@ class MCParams:
     # default drain-argmin scoring is pinned by the legacy goldens
     stepping: str = "adaptive"    # "adaptive" (event-horizon) | "slot"
     use_kernel: bool | None = None  # None: Pallas on accelerators, jnp on CPU
-    interpret: bool | None = None   # None: interpret only on CPU
 
 
 @dataclasses.dataclass(frozen=True)
@@ -659,7 +660,7 @@ def _select(u, elig, k):
 def _mc_run_impl(arr: dict, sc: dict, ev: EventTensor,
                  state: EngineState | None = None, *, s: int,
                  policy: PolicyConfig, steal_rounds: int, mig_rounds: int,
-                 mem_safe: bool, use_kernel: bool, interpret: bool,
+                 mem_safe: bool, use_kernel: bool,
                  stepping: str, ac_aligned: bool,
                  orphan_retry: int = 16, dest_cascade: bool = False,
                  return_state: bool = False) -> dict:
@@ -796,8 +797,9 @@ def _mc_run_impl(arr: dict, sc: dict, ev: EventTensor,
         ohb = ohp[:, :, bi]
 
         def col_sum(w):
-            """Per-column sum of the [S, B] weight vector ``w``."""
-            return jnp.einsum("sbv,sb->sv", ohp, w)
+            """Per-column sum of the [S, B] weight vector ``w`` (f32 on
+            every backend: a TPU's default dot precision is bf16)."""
+            return jnp.einsum("sbv,sb->sv", ohp, w, precision=HIGHEST)
 
         rank = jnp.take_along_axis(cum, assign[:, :, None],
                                    axis=2)[:, :, 0] - 1.0
@@ -924,7 +926,7 @@ def _mc_run_impl(arr: dict, sc: dict, ev: EventTensor,
                 # fused Pallas kernel: progress decrement + the [S, V]
                 # reductions of the advanced state in one streamed pass
                 rem, _, cnt, maxw = mc_span_advance(
-                    assign, rem, drem0, mf, v=v, interpret=interpret)
+                    assign, rem, drem0, mf, v=v)
             else:
                 rem = jnp.where(pending,
                                 jnp.maximum(rem - mf[:, None] * drem0, 0.0),
@@ -946,7 +948,7 @@ def _mc_run_impl(arr: dict, sc: dict, ev: EventTensor,
             # inside the event branches.  The one-hot/cumsum above remains
             # only for the queue rank; a TPU-native rank kernel is the
             # open item (DESIGN.md §2.3).
-            _, cnt, maxw = mc_vm_stats(assign, rem, v=v, interpret=interpret)
+            _, cnt, maxw = mc_vm_stats(assign, rem, v=v)
         else:
             maxw = jnp.max(ohp * rem[:, :, None], axis=1) \
                 if policy.deferred_migration else None
@@ -1033,8 +1035,7 @@ def _mc_run_impl(arr: dict, sc: dict, ev: EventTensor,
 
             def migt(ops):
                 rem2, assign, mode, vstate, boot, rcv, orph = ops
-                load = mc_vm_stats(assign, rem2, v=v,
-                                   interpret=interpret)[0] \
+                load = mc_vm_stats(assign, rem2, v=v)[0] \
                     if use_kernel else col_sum(rem2 * (rem2 > 0))
                 (rem2, assign, mode, vstate, boot, rcv,
                  moved) = _migrate_spread(
@@ -1071,8 +1072,7 @@ def _mc_run_impl(arr: dict, sc: dict, ev: EventTensor,
                     rem2, assign, mode, vstate, boot, rcv, orph = ops
                 else:
                     rem2, assign, mode, vstate, boot, rcv = ops
-                load = mc_vm_stats(assign, rem2, v=v,
-                                   interpret=interpret)[0] \
+                load = mc_vm_stats(assign, rem2, v=v)[0] \
                     if use_kernel else col_sum(rem2 * (rem2 > 0))
                 out = _migrate_spread(
                     do_hib, affected, rem2, load, vstate, boot, credits,
@@ -1121,8 +1121,7 @@ def _mc_run_impl(arr: dict, sc: dict, ev: EventTensor,
                     rem2, assign, mode, vstate, boot, rcv, orph = ops
                 else:
                     rem2, assign, mode, vstate, boot, rcv = ops
-                load = mc_vm_stats(assign, rem2, v=v,
-                                   interpret=interpret)[0] \
+                load = mc_vm_stats(assign, rem2, v=v)[0] \
                     if use_kernel else col_sum(rem2 * (rem2 > 0))
                 out = _migrate_spread(
                     do2, aff2, rem2, load, vstate, boot, credits, assign,
@@ -1226,8 +1225,7 @@ def _mc_run_impl(arr: dict, sc: dict, ev: EventTensor,
 
             def retry(ops):
                 rem2, assign, mode, vstate, boot, rcv, orph, oret = ops
-                load = mc_vm_stats(assign, rem2, v=v,
-                                   interpret=interpret)[0] \
+                load = mc_vm_stats(assign, rem2, v=v)[0] \
                     if use_kernel else col_sum(rem2 * (rem2 > 0))
                 (rem2, assign, mode, vstate, boot, rcv,
                  moved) = _migrate_spread(
@@ -1293,6 +1291,55 @@ def _mc_run_impl(arr: dict, sc: dict, ev: EventTensor,
     return res
 
 
+def scenario_mesh(ev: EventTensor):
+    """The device mesh an event tensor's scenario axis is split over
+    (``sim.fleet.shard_events``), or None for a single-device tensor."""
+    sh = getattr(ev.hib_k, "sharding", None)
+    if isinstance(sh, NamedSharding) and len(sh.device_set) > 1:
+        return sh.mesh
+    return None
+
+
+def _mc_run(arr: dict, sc: dict, ev: EventTensor,
+            state: EngineState | None = None, *, s: int,
+            mesh: Mesh | None = None, policy: PolicyConfig,
+            steal_rounds: int, mig_rounds: int, mem_safe: bool,
+            use_kernel: bool, stepping: str, ac_aligned: bool,
+            orphan_retry: int = 16, dest_cascade: bool = False,
+            return_state: bool = False) -> dict:
+    """The engine over S scenarios, on one device or — with ``mesh`` —
+    under ``shard_map`` with every device running its own S / n slice.
+
+    Scenarios are independent, so each device steps its slice in its own
+    while-loop with no collective; the Pallas kernels cannot be
+    partitioned by GSPMD and need exactly this per-device program.  Plan
+    arrays are replicated, except the megabatch row-parametric layout's
+    [S, ·] rows (and the [S] per-row scalars), which split with the
+    scenarios.  ``n_steps`` is the maximum over the devices' loops."""
+    kw = dict(policy=policy, steal_rounds=steal_rounds,
+              mig_rounds=mig_rounds, mem_safe=mem_safe,
+              use_kernel=use_kernel, stepping=stepping,
+              ac_aligned=ac_aligned, orphan_retry=orphan_retry,
+              dest_cascade=dest_cascade, return_state=return_state)
+    if mesh is None:
+        return _mc_run_impl(arr, sc, ev, state, s=s, **kw)
+    rows, rep = PartitionSpec(mesh.axis_names[0]), PartitionSpec()
+    rowp = arr["speed"].ndim == 2
+    arr_spec = {k: rows if rowp and k != "burst_idx" else rep for k in arr}
+    sc_spec = {k: rows if jnp.ndim(x) == 1 else rep for k, x in sc.items()}
+
+    def local(arr, sc, ev, state):
+        out = _mc_run_impl(arr, sc, ev, state, s=s // mesh.size, **kw)
+        out["n_steps"] = out["n_steps"][None]
+        return out
+
+    out = jax.shard_map(local, mesh=mesh,
+                        in_specs=(arr_spec, sc_spec, rows, rows),
+                        out_specs=rows, check_vma=False)(arr, sc, ev, state)
+    out["n_steps"] = jnp.max(out["n_steps"])
+    return out
+
+
 @functools.lru_cache(maxsize=2)
 def _mc_jit(donate: bool):
     """jit the engine, optionally donating the event tensor's buffers —
@@ -1301,9 +1348,9 @@ def _mc_jit(donate: bool):
     accelerators.  ``run_mc`` donates (it owns a fresh tensor per call);
     ``run_mc_events`` defaults to not donating because callers routinely
     reuse pregenerated tensors (parity tests, fleet warm-up runs)."""
-    return jax.jit(_mc_run_impl, static_argnames=(
-        "s", "policy", "steal_rounds", "mig_rounds", "mem_safe",
-        "use_kernel", "interpret", "stepping", "ac_aligned",
+    return jax.jit(_mc_run, static_argnames=(
+        "s", "mesh", "policy", "steal_rounds", "mig_rounds", "mem_safe",
+        "use_kernel", "stepping", "ac_aligned",
         "orphan_retry", "dest_cascade", "return_state"),
         donate_argnums=(2,) if donate else ())
 
@@ -1311,6 +1358,26 @@ def _mc_jit(donate: bool):
 # ---------------------------------------------------------------------------
 # Public API
 # ---------------------------------------------------------------------------
+def engine_args(view: PolicyConfig, params: MCParams, cfg: CloudConfig,
+                mem_safe: bool) -> dict:
+    """The engine's static keyword arguments for one call.  ``view`` is
+    the policy's ``engine_view()``: the engine branches only on the
+    dynamics axes, so same-dynamics lattice policies share one
+    compilation.  Reductions run in the Pallas kernels on accelerators
+    and on the jnp one-hot path on the CPU unless ``params.use_kernel``
+    says otherwise; the kernels' interpret mode follows the backend
+    (``kernels.sched_fitness.resolve_interpret``)."""
+    use_kernel = jax.default_backend() != "cpu" \
+        if params.use_kernel is None else params.use_kernel
+    return dict(policy=view, steal_rounds=params.steal_rounds,
+                mig_rounds=params.mig_rounds, mem_safe=mem_safe,
+                use_kernel=use_kernel,
+                stepping=params.stepping,
+                ac_aligned=_dt_aligned(cfg, params.dt),
+                orphan_retry=params.orphan_retry,
+                dest_cascade=params.dest_cascade)
+
+
 def _dt_aligned(cfg: CloudConfig, dt: float) -> bool:
     """True when ``dt`` divides both ω and AC, so boundary slots can use
     exact integer arithmetic (and the slot engine is admissible)."""
@@ -1381,10 +1448,10 @@ def run_mc_events(job: Job, plan: PrimaryPlan, cfg: CloudConfig,
     ignored here), a V axis that must match the plan's launchable columns,
     and a slot horizon the engine runs to (events never fire past the
     deadline by the tensor contract, but the run continues to the tensor's
-    horizon so late scenarios finish).  ``ev`` may carry any
-    ``jax.sharding`` placement on the scenario axis — the engine's state
-    is batched over S, so GSPMD shards the whole run with it
-    (``sim.fleet`` uses this to spread a grid across devices).  Under
+    horizon so late scenarios finish).  ``ev`` may be split over a
+    device mesh on its scenario axis (``sim.fleet.shard_events``); the
+    run then splits with it, one engine loop per device (``_mc_run``).
+    Under
     adaptive stepping the tensor's next-event index is used (and built
     here if the tensor arrived without one).  ``donate=True`` lets XLA
     consume the tensor's buffers (don't reuse ``ev`` afterwards).
@@ -1456,21 +1523,9 @@ def run_mc_events(job: Job, plan: PrimaryPlan, cfg: CloudConfig,
     want_state = bool(stop_s is not None) if return_state is None \
         else return_state
     on_cpu = jax.default_backend() == "cpu"
-    use_kernel = params.use_kernel if params.use_kernel is not None \
-        else not on_cpu
-    interpret = params.interpret if params.interpret is not None else on_cpu
     out = _mc_jit(donate and not on_cpu)(
-        # static key: the engine branches only on the dynamics axes, so
-        # same-dynamics lattice policies share one compilation
-        arr, sc, ev, state, s=ev.n_scenarios,
-        policy=plan.policy.engine_view(),
-        steal_rounds=params.steal_rounds,
-        mig_rounds=params.mig_rounds, mem_safe=mem_safe,
-        use_kernel=use_kernel, interpret=interpret,
-        stepping=params.stepping,
-        ac_aligned=_dt_aligned(cfg, params.dt),
-        orphan_retry=params.orphan_retry,
-        dest_cascade=params.dest_cascade,
+        arr, sc, ev, state, s=ev.n_scenarios, mesh=scenario_mesh(ev),
+        **engine_args(plan.policy.engine_view(), params, cfg, mem_safe),
         return_state=want_state)
     out = jax.device_get(out)
     unfinished = out["unfinished"].astype(int)

@@ -57,9 +57,9 @@ from repro.core.types import CloudConfig, Job
 from .fleet import (FleetResult, pad_scenarios, scenario_sharding,
                     shard_events)
 from .market import EventTensor, MarketProcess, as_process
-from .mc_engine import (MCParams, _dt_aligned, _check_dt, _mc_jit,
-                        _plan_arrays_cached, _scalars, dist_stats,
-                        n_slots_for)
+from .mc_engine import (MCParams, _check_dt, _mc_jit, _plan_arrays_cached,
+                        _scalars, dist_stats, engine_args, n_slots_for,
+                        scenario_mesh)
 from .workloads import make_job
 
 __all__ = ["B_MULT", "SLOT_MULT", "V_MULT", "ScenarioBudget",
@@ -301,16 +301,9 @@ def _fused_inputs(cells: list[_Cell], evs: list[EventTensor],
 def _run_fused(arr, sc, ev, view, params: MCParams, cfg: CloudConfig,
                mem_safe: bool, donate: bool) -> dict:
     on_cpu = jax.default_backend() == "cpu"
-    use_kernel = params.use_kernel if params.use_kernel is not None \
-        else not on_cpu
-    interpret = params.interpret if params.interpret is not None else on_cpu
     out = _mc_jit(donate and not on_cpu)(
-        arr, sc, ev, s=ev.n_scenarios, policy=view,
-        steal_rounds=params.steal_rounds, mig_rounds=params.mig_rounds,
-        mem_safe=mem_safe, use_kernel=use_kernel, interpret=interpret,
-        stepping=params.stepping,
-        ac_aligned=_dt_aligned(cfg, params.dt),
-        orphan_retry=params.orphan_retry)
+        arr, sc, ev, s=ev.n_scenarios, mesh=scenario_mesh(ev),
+        **engine_args(view, params, cfg, mem_safe))
     return jax.device_get(out)
 
 

@@ -6,10 +6,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                    # container has no hypothesis
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.data.pipeline import DataConfig, TokenPipeline
 from repro.ft.checkpoint import CheckpointManager, ovh_checkpoint_period

@@ -1,4 +1,7 @@
-"""Per-kernel shape/dtype sweeps vs the pure-jnp oracles (interpret mode)."""
+"""Per-kernel shape/dtype sweeps vs the pure-jnp oracles.
+
+The scheduling kernels run in Pallas interpret mode here because the test
+backend is the CPU (``sched_fitness.resolve_interpret``)."""
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -33,8 +36,7 @@ def test_sched_fitness_matches_ref(p, b, v):
     spot = jnp.asarray(rng.integers(0, 2, v), jnp.float32)
     kw = dict(dspot=2240.0, deadline=2700.0, alpha=0.5, cost_scale=0.2,
               boot_s=60.0)
-    got = population_fitness(alloc, e, rm, cores, mem, price, spot, **kw,
-                             interpret=True)
+    got = population_fitness(alloc, e, rm, cores, mem, price, spot, **kw)
     want = population_fitness_ref(alloc, e, rm, cores, mem, price, spot,
                                   **kw)
     for g, w in zip(got, want):
@@ -54,7 +56,7 @@ def test_mc_vm_reduce_matches_ref(s, b, v):
     w[rng.uniform(size=(s, b)) < 0.3] = 0.0         # done tasks
     cols_j = jnp.asarray(cols, jnp.int32)
     w_j = jnp.asarray(w, jnp.float32)
-    got = mc_vm_reduce(cols_j, w_j, v, interpret=True)
+    got = mc_vm_reduce(cols_j, w_j, v)
     want = mc_vm_stats_ref(cols_j, w_j, v)
     for name, g, ww in zip(("load", "cnt", "maxw"), got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(ww),
@@ -65,7 +67,7 @@ def test_mc_vm_stats_masks_done_tasks():
     """The ops wrapper ignores rem <= 0 regardless of the column value."""
     assign = jnp.asarray([[0, 0, 1, 2]], jnp.int32)
     rem = jnp.asarray([[10.0, 0.0, 5.0, 0.0]], jnp.float32)
-    load, cnt, maxw = mc_vm_stats(assign, rem, v=3, interpret=True)
+    load, cnt, maxw = mc_vm_stats(assign, rem, v=3)
     np.testing.assert_allclose(np.asarray(load), [[10.0, 5.0, 0.0]])
     np.testing.assert_allclose(np.asarray(cnt), [[1.0, 1.0, 0.0]])
     np.testing.assert_allclose(np.asarray(maxw), [[10.0, 5.0, 0.0]])
@@ -81,12 +83,12 @@ def test_mc_vm_reduce_megabatch_pad_columns_stay_empty():
     v_real, v_pad = 5, 8
     cols = jnp.asarray(rng.integers(0, v_real, (4, 16)), jnp.int32)
     w = jnp.asarray(rng.uniform(1.0, 9.0, (4, 16)), jnp.float32)
-    load, cnt, maxw = mc_vm_reduce(cols, w, v=v_pad, interpret=True)
+    load, cnt, maxw = mc_vm_reduce(cols, w, v=v_pad)
     for name, x in (("load", load), ("cnt", cnt), ("maxw", maxw)):
         assert not np.asarray(x)[:, v_real:].any(), name
     # a stray out-of-range column is ignored, not misattributed
     load2, cnt2, _ = mc_vm_reduce(cols.at[0, 0].set(v_pad + 3), w,
-                                  v=v_pad, interpret=True)
+                                  v=v_pad)
     assert not np.asarray(cnt2)[:, v_real:].any()
     np.testing.assert_allclose(np.asarray(cnt2).sum(),
                                np.asarray(cnt).sum() - 1.0)
@@ -116,9 +118,9 @@ def _assert_delta_matches(got, want):
 
 def _delta_vs_oracles(alloc, t_idx, dest, e, rm, cores, mem, price, spot,
                       **kw):
-    base = population_reduce(alloc, e, rm, interpret=True)
+    base = population_reduce(alloc, e, rm)
     got = delta_fitness(alloc, t_idx, dest, base, e, rm, cores, mem, price,
-                        spot, **kw, interpret=True)
+                        spot, **kw)
     want = delta_fitness_ref(alloc, t_idx, dest, e, rm, cores, mem, price,
                              spot, **kw)
     _assert_delta_matches(got, want)
@@ -126,8 +128,7 @@ def _delta_vs_oracles(alloc, t_idx, dest, e, rm, cores, mem, price, spot,
     p, b = alloc.shape
     k = t_idx.shape[1]
     cand = apply_moves(alloc, t_idx, dest).reshape(p * k, b)
-    full = population_fitness(cand, e, rm, cores, mem, price, spot, **kw,
-                              interpret=True)
+    full = population_fitness(cand, e, rm, cores, mem, price, spot, **kw)
     _assert_delta_matches(got, [f.reshape(p, k) for f in full])
     return got
 
@@ -179,11 +180,11 @@ def test_delta_fitness_noop_move_keeps_base_fitness():
     e, rm, cores, mem, price, spot = _fitness_problem(rng, b, v)
     kw = dict(dspot=2240.0, deadline=2700.0, alpha=0.5, cost_scale=0.2,
               boot_s=60.0)
-    base = population_reduce(alloc, e, rm, interpret=True)
+    base = population_reduce(alloc, e, rm)
     fit, _, _ = delta_fitness(alloc, t_idx, dest, base, e, rm, cores, mem,
-                              price, spot, **kw, interpret=True)
+                              price, spot, **kw)
     fit0, _, _ = population_fitness(alloc, e, rm, cores, mem, price, spot,
-                                    **kw, interpret=True)
+                                    **kw)
     np.testing.assert_allclose(np.asarray(fit),
                                np.tile(np.asarray(fit0)[:, None], (1, k)),
                                rtol=1e-6, atol=1e-6)
@@ -228,9 +229,9 @@ def _insert_problem(rng, b, v):
 
 def _insert_vs_oracle(alloc, dest, e, rm, e_new, rm_new, cores, mem,
                       price, spot, **kw):
-    base = population_reduce(alloc, e, rm, interpret=True)
+    base = population_reduce(alloc, e, rm)
     got = insert_tasks(alloc, dest, base, e, rm, e_new, rm_new, cores,
-                       mem, price, spot, **kw, interpret=True)
+                       mem, price, spot, **kw)
     want = insert_tasks_ref(alloc, dest, e, rm, e_new, rm_new, cores,
                             mem, price, spot, **kw)
     _assert_delta_matches(got, want)

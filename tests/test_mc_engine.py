@@ -129,7 +129,7 @@ def test_kernel_engine_matches_jnp_engine(j60, plan_bh, sc_name):
     a = run_mc(j60, plan_bh, CFG, SCENARIOS.get(sc_name, SC_NONE),
                MCParams(**base, use_kernel=False))
     b = run_mc(j60, plan_bh, CFG, SCENARIOS.get(sc_name, SC_NONE),
-               MCParams(**base, use_kernel=True, interpret=True))
+               MCParams(**base, use_kernel=True))
     np.testing.assert_allclose(a.cost, b.cost, rtol=1e-6)
     np.testing.assert_allclose(a.makespan, b.makespan, rtol=1e-6)
     np.testing.assert_array_equal(a.n_hibernations, b.n_hibernations)

@@ -36,10 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                                   # pragma: no cover
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro import api
 from repro.core.dynamic import ArrivalPolicy
@@ -258,7 +255,7 @@ def test_verdict_reasons_are_ordered():
             assert r.column == -1
 
 
-@settings(max_examples=2)
+@settings(max_examples=2, deadline=None)
 @given(seed=st.integers(0, 10_000))
 def test_verdicts_deterministic_per_seed(seed):
     """The verdict sequence is a pure function of (stream, seed): two

@@ -212,7 +212,7 @@ def test_span_kernel_matches_oracle():
     rem = rem * (jax.random.uniform(k3, (s, b)) > 0.2)
     drem = jax.random.uniform(k4, (s, b)) * 0.5
     m = jax.numpy.asarray([0., 1., 3., 10., 40., 2., 7.])
-    got = mc_span_advance(assign, rem, drem, m, v=v, interpret=True)
+    got = mc_span_advance(assign, rem, drem, m, v=v)
     want = mc_span_advance_ref(
         assign, rem, jax.numpy.where(rem > 0, drem, 0.0), m, v)
     for g, w in zip(got, want):

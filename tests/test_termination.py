@@ -14,14 +14,13 @@ and always re-enter Alg. 4 migration.  This suite pins:
     column can never be revived by a later resume;
   * adaptive-vs-slot stepping parity — the event-horizon jump lattice
     can never skip a terminate slot;
-  * property invariants (hypothesis, or the deterministic fallback
-    shim): a terminated VM never bills past its terminate instant;
-    preemption rollback never exceeds the checkpoint floor; total work
-    (hence cost at any fixed rate) is monotone non-decreasing in the
-    checkpoint overhead budget; and under an immediate-migration policy
-    a terminate-only trace is *equal* to the same trace hibernating
-    forever (no resumes) — all across the full checkpoint axis
-    (periodic | off | random);
+  * property invariants (hypothesis): a terminated VM never bills past
+    its terminate instant; preemption rollback never exceeds the
+    checkpoint floor; total work (hence cost at any fixed rate) is
+    monotone non-decreasing in the checkpoint overhead budget; and
+    under an immediate-migration policy a terminate-only trace is
+    *equal* to the same trace hibernating forever (no resumes) — all
+    across the full checkpoint axis (periodic | off | random);
   * a trace-hash golden (tests/data/termination_golden.json) freezing
     one terminating Poisson run on both engines.
 """
@@ -37,10 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                                   # pragma: no cover
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro import api
 from repro.core.dynamic import POLICIES as POLICY_REGISTRY
@@ -395,7 +391,7 @@ def test_termination_trace_golden():
 # ---------------------------------------------------------------------------
 # Property sweep (all checkpoint axis points: periodic | off | random)
 # ---------------------------------------------------------------------------
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 @given(t=st.floats(120.0, 380.0), which=st.integers(0, 3),
        m=st.integers(0, 2))
 def test_terminated_vm_never_bills_after_terminate(t, which, m):
@@ -477,7 +473,7 @@ def test_des_cost_monotone_in_overhead():
     assert costs == sorted(costs)
 
 
-@settings(max_examples=5)
+@settings(max_examples=5, deadline=None)
 @given(times=st.lists(st.floats(120.0, 380.0), min_size=1, max_size=3),
        m=st.integers(0, 2))
 def test_terminate_equals_hibernate_forever_under_migration(times, m):
