@@ -37,8 +37,7 @@ from repro.sim.workloads import make_job
 ILS_FAST = ILSParams(max_iteration=25, max_attempt=15, seed=3)
 BATCHED_FAST = BatchedILSParams(iterations=25, seed=3)
 POLICY_GRID = ("burst-hads", "hads", "ils-ondemand")
-#: beyond-paper lattice cells tracked for perf/behaviour trajectory
-#: (BENCH_dynamic.json rollup): the paper policies ± one axis each.
+#: beyond-paper lattice cells: the paper policies ± one axis each.
 LATTICE_GRID = ("burst-hads+nosteal", "hads+burst", "hads+steal",
                 "burst-hads+freeze")
 
@@ -125,11 +124,8 @@ def lattice(job_names: tuple[str, ...] = ("J60",), s: int = 64,
             dt: float = 30.0) -> list[dict]:
     """Policy-lattice cell grid: the paper policies perturbed one axis at
     a time (``LATTICE_GRID``), each (job, policy) run as one fused
-    engine call over sc5 + bursty-Weibull tensors.  Rows feed the
-    root-level ``BENCH_dynamic.json`` rollup (``benchmarks/run.py``) so
-    the new combos get steps/throughput trajectory coverage from day one
-    — ``steps`` is deterministic per grid+seed and is what the CI gate
-    (``scripts/check_bench_regression.py``) diffs."""
+    engine call over sc5 + bursty-Weibull tensors; ``steps`` is
+    deterministic per grid+seed."""
     cfg = CloudConfig()
     params = MCParams(n_scenarios=s, dt=dt, seed=0)
     rows: list[dict] = []

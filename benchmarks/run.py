@@ -56,134 +56,6 @@ def _write_json(path: str, rows: list[dict]) -> None:
     print(f"# artifact -> {path}")
 
 
-#: repo-root rollup the CI bench-regression gate diffs against
-#: (scripts/check_bench_regression.py); keep it at the root so the
-#: committed baseline rides every checkout.
-DYNAMIC_ROLLUP = os.path.join(os.path.dirname(__file__), "..",
-                              "BENCH_dynamic.json")
-
-
-def dynamic_rollup(sim_rows: list[dict], smoke: bool,
-                   outdir: str, lattice_rows: list[dict] = (),
-                   mega_rows: list[dict] = (),
-                   service_rows: list[dict] = (),
-                   recovery_rows: list[dict] = ()) -> list[dict]:
-    """Headline dynamic-engine throughput per (job, policy, process, S,
-    dt, stepping) + slots-skipped fraction, written to the root-level
-    ``BENCH_dynamic.json`` and appended to ``results/trajectory.jsonl``
-    so the perf history stays machine-readable across PRs.
-
-    Rollup rows for keys not re-measured by this run (e.g. the committed
-    full-size rows during a ``--smoke`` CI run) are carried over from the
-    existing artifact, so the baseline keys survive partial runs.
-    """
-    rows = []
-    for r in sim_rows:
-        if r.get("table") != "sim_bench":
-            continue
-        key = {k: r[k] for k in ("job", "policy", "process", "s", "dt")}
-        for stepping in ("adaptive", "slot"):
-            row = {"table": "dynamic", **key, "stepping": stepping,
-                   "scen_per_s": r[f"{stepping}_scen_per_s"],
-                   "steps": r[f"steps_{stepping}"],
-                   "slots_skipped_frac":
-                       r["slots_skipped_frac"] if stepping == "adaptive"
-                       else 0.0}
-            if "des_scen_per_s" in r:
-                row["des_scen_per_s"] = r["des_scen_per_s"]
-                row["vs_des"] = round(r[f"{stepping}_scen_per_s"]
-                                      / r["des_scen_per_s"], 2)
-            row["vs_slot"] = round(r[f"{stepping}_scen_per_s"]
-                                   / r["slot_scen_per_s"], 2)
-            rows.append(row)
-    # policy-lattice cells (fleet_bench.lattice): adaptive-only fused
-    # runs — `steps` is the deterministic signal the CI gate diffs
-    for r in lattice_rows:
-        if r.get("table") != "lattice":
-            continue
-        rows.append({"table": "dynamic",
-                     **{k: r[k] for k in ("job", "policy", "process",
-                                          "s", "dt")},
-                     "stepping": "adaptive",
-                     "scen_per_s": r["scen_per_s"], "steps": r["steps"],
-                     "slots_skipped_frac": r["slots_skipped_frac"]})
-
-    # megabatch grid rows (fleet_bench.megabatch_grid): whole-grid fused
-    # vs per-cell throughput — `vs_loop` (same-process ratio, hardware
-    # cancels) and the call/group counts are the gate's signals
-    for r in mega_rows:
-        if r.get("table") != "megabatch":
-            continue
-        rows.append({"table": "megabatch",
-                     **{k: r[k] for k in ("job", "policy", "process",
-                                          "s", "dt")},
-                     "stepping": "adaptive",
-                     "scen_per_s": r["mega_scen_per_s"],
-                     "vs_loop": r["vs_loop"],
-                     "n_engine_calls": r["n_engine_calls"],
-                     "n_groups": r["n_groups"],
-                     "n_cells": r["n_cells"]})
-
-    # online service-mode rows (service_bench): streaming admission over
-    # the mid-horizon engine — `admitted` and `slo_met_frac` are the
-    # deterministic gate signals, the wall rates ride informationally
-    for r in service_rows:
-        if r.get("table") != "service":
-            continue
-        rows.append({"table": "service",
-                     **{k: r[k] for k in ("job", "policy", "process",
-                                          "s", "dt")},
-                     "stepping": "service",
-                     "scen_per_s": r["arrivals_per_wall_s"],
-                     "arrivals": r["arrivals"],
-                     "admitted": r["admitted"],
-                     "rejected": r["rejected"],
-                     "slo_met_frac": r["slo_met_frac"],
-                     "replan_p95_ms": r["replan_p95_ms"]})
-
-    # fault-recovery rows (sim_bench.recovery, DESIGN.md §2.10): the
-    # chaos grid's deterministic recovery signals — the gate hard-fails
-    # any fresh stranded_tasks > 0 and watches the retry effort
-    for r in recovery_rows:
-        if r.get("table") != "recovery":
-            continue
-        rows.append({"table": "recovery",
-                     **{k: r[k] for k in ("job", "policy", "process",
-                                          "s", "dt")},
-                     "stepping": "recovery",
-                     "stranded_tasks": r["stranded_tasks"],
-                     "orphan_retry_rounds_mean":
-                         r["orphan_retry_rounds_mean"],
-                     "work_conserved": r["work_conserved"],
-                     "mean_terminations": r["mean_terminations"],
-                     "deadline_met_frac": r["deadline_met_frac"]})
-
-    def key_of(row):
-        return tuple(row.get(k) for k in ("job", "policy", "process",
-                                          "s", "dt", "stepping"))
-
-    fresh = {key_of(r) for r in rows}
-    try:
-        with open(DYNAMIC_ROLLUP) as f:
-            for old in json.load(f).get("rows", []):
-                if key_of(old) not in fresh:
-                    # flagged so readers and the CI gate can tell a
-                    # carried-over number from a re-measured one
-                    rows.append({**old, "carried": True})
-    except (OSError, ValueError):
-        pass
-    _write_json(os.path.abspath(DYNAMIC_ROLLUP), rows)
-
-    traj = os.path.join(outdir, "trajectory.jsonl")
-    with open(traj, "a") as f:
-        f.write(json.dumps({"unix_time": round(time.time()),
-                            "smoke": smoke,
-                            "rows": [r for r in rows
-                                     if key_of(r) in fresh]}) + "\n")
-    print(f"# trajectory -> {traj}")
-    return rows
-
-
 def main() -> None:
     compile_cache.enable()
     ap = argparse.ArgumentParser()
@@ -213,27 +85,21 @@ def main() -> None:
     _write_json(os.path.join(outdir, "BENCH_sim.json"), sim_rows)
 
     print("# Policy-lattice cells (paper policies ± one axis, fused)")
-    lattice_rows = emit("lattice",
-                        fleet_bench.lattice_smoke() if args.smoke
-                        else fleet_bench.lattice(), fh)
+    emit("lattice", fleet_bench.lattice_smoke() if args.smoke
+         else fleet_bench.lattice(), fh)
 
     print("# Megabatch engine: whole grid fused vs per-cell pipeline")
-    mega_rows = emit("megabatch",
-                     fleet_bench.megabatch_smoke() if args.smoke
-                     else fleet_bench.megabatch_grid(), fh)
+    emit("megabatch", fleet_bench.megabatch_smoke() if args.smoke
+         else fleet_bench.megabatch_grid(), fh)
 
     print("# Online service mode: streaming admission + rolling replans")
     from benchmarks import service_bench
-    service_rows = emit("service",
-                        service_bench.smoke() if args.smoke
-                        else service_bench.run(), fh)
+    emit("service", service_bench.smoke() if args.smoke
+         else service_bench.run(), fh)
 
     print("# Fault recovery: chaos grid, orphan-retry + stranded signals")
-    recovery_rows = emit("recovery",
-                         sim_bench.recovery_smoke() if args.smoke
-                         else sim_bench.recovery(), fh)
-    dynamic_rollup(sim_rows, args.smoke, outdir, lattice_rows, mega_rows,
-                   service_rows, recovery_rows)
+    emit("recovery", sim_bench.recovery_smoke() if args.smoke
+         else sim_bench.recovery(), fh)
 
     print("# Market/fleet: jobs x policies x market-process grid "
           "(sharded batch vs per-cell loop)")

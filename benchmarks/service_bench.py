@@ -6,11 +6,10 @@ re-entry — under the sc5 market process.
 The stream is pressured on purpose (burst factor 8 over a ~1000s span,
 900s relative deadlines) so the three-verdict admission contract is
 actually exercised: the committed artifact carries a CONGESTION tail,
-not a trivially-all-SUCCESS run.  The row lands in BENCH_dynamic.json
-under ``stepping="service"``; its gate signals are the *deterministic*
-stream outcomes (``admitted`` count and ``slo_met_frac`` — fixed given
-seeds and code), while wall-clock rates (arrivals/s served, replan p95)
-ride along informationally like every other throughput number.
+not a trivially-all-SUCCESS run.  The *deterministic* stream outcomes
+(``admitted`` count and ``slo_met_frac``, fixed given seeds and code)
+are the row's signals; its wall-clock rates (arrivals/s served, replan
+p95) are CPU walls.  Chip measurements are ``bench/run.py``'s (PERF.md).
 """
 from __future__ import annotations
 

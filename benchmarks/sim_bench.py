@@ -14,8 +14,7 @@ engines care about:
 
 The event tensor for each cell is pregenerated **outside the timed
 region** (the engine's steady-state throughput is what the artifact
-tracks; ``run_mc``-style sampling cost is its own column in
-BENCH_dynamic.json's trajectory) and both steppings are timed warm over
+tracks; ``run_mc``-style sampling cost is left out) and both steppings are timed warm over
 the *identical* tensor, so ``adaptive_vs_slot`` is pure hot-loop
 efficiency.  The DES replays the same Poisson scenarios one trace per
 python loop; non-Poisson processes have no DES equivalent and skip the
@@ -48,7 +47,7 @@ ILS_FAST = ILSParams(max_iteration=25, max_attempt=15, seed=3)
 #: sc5 with half its interruptions escalated to spot *terminations*
 #: (§2.8): the terminating cell times the term-direction program (gated
 #: at trace time, so the other cells still compile the historical
-#: two-direction program) and tracks its throughput in BENCH_dynamic.
+#: two-direction program).
 def process_grid(deadline_s: float) -> list:
     sc5 = as_process("sc5")
     return [sc5, as_process("sc1"),

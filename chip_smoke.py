@@ -51,7 +51,8 @@ REF_JOB, REF_POLICY, REF_PROCESS = "ED200", "burst-hads", "sc5"
 #: ILS shapes (``BatchedILSParams`` defaults)
 ILS_P, ILS_K, ILS_N = 32, 16, 4
 #: the served stream's outcome on the CPU under JAX 0.9.0 (489 and 0.908
-#: in ``BENCH_dynamic.json`` date from the older RNG stream)
+#: under the older RNG stream); chip timings come from ``bench/run.py``
+#: and PERF.md
 CPU_ADMITTED, CPU_SLO_MET = 490, 0.9102
 #: tolerances shared with the tests: per-scenario kernel vs jnp engine
 #: (tests/test_mc_engine.py), kernel vs oracle (tests/test_kernels.py),

@@ -11,10 +11,9 @@ violation:
   EngineState instances (no engine compile).
 * ``--retrace`` compile-count probes of the public entry points against
   the committed ``src/repro/analysis/budgets.json`` ratchet; writes the
-  measured counts to ``results/compile_counts.json`` for the bench
-  regression gate.  ``--smoke`` shrinks the lattice sweep to its first
-  4 views (CI's tier-1 budget) — the repeat/ils/megabatch/service
-  probes are already tiny.
+  measured counts to ``results/compile_counts.json``.  ``--smoke``
+  shrinks the lattice sweep to its first 4 views (CI's tier-1 budget) —
+  the repeat/ils/megabatch/service probes are already tiny.
 
 No flags = all passes (full retrace).  The driver must run in a fresh
 process: the budgets assume cold jit caches.

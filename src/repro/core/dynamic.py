@@ -49,6 +49,7 @@ import dataclasses
 import warnings
 
 from ..ft.checkpoint import CHECKPOINT_MODES
+from ..obs import span
 from .burst_alloc import burst_allocation
 from .dspot import compute_dspot
 from .greedy import initial_solution
@@ -389,35 +390,36 @@ def build_primary_map(job: Job, cfg: CloudConfig, policy: PolicyConfig,
     ``core.ils_jax.BatchedILSParams``) to control population/proposal
     sizes explicitly — it takes precedence over the derived hand-off.
     """
-    pool = cfg.instance_pool()
-    if policy.market == Market.SPOT:
-        dspot = compute_dspot(job.deadline_s, job.tasks, cfg)
-    else:
-        dspot = job.deadline_s  # on-demand VMs don't hibernate
-
     if engine is None:
         engine = "batched" if policy.planner == "ils-batched" else "exact"
-
-    if policy.primary == "ils":
-        if engine == "batched":
-            from .ils_jax import run_batched_ils
-            bp = batched_params if batched_params is not None \
-                else _batched_params_from(params)
-            sol = run_batched_ils(job.tasks, pool, cfg, dspot,
-                                  job.deadline_s, bp,
-                                  market=policy.market).solution
-        elif engine == "exact":
-            sol = run_ils(job.tasks, pool, cfg, dspot, job.deadline_s,
-                          params, market=policy.market).solution
+    with span("plan", policy=policy.name, engine=engine,
+              n_tasks=len(job.tasks)):
+        pool = cfg.instance_pool()
+        if policy.market == Market.SPOT:
+            dspot = compute_dspot(job.deadline_s, job.tasks, cfg)
         else:
-            raise ValueError(f"unknown ILS engine {engine!r} "
-                             "(exact/batched)")
-    else:
-        sol = initial_solution(job.tasks, pool, cfg, dspot,
-                               market=policy.market)
-        sol.selected_uids = set(sol.used_uids())
+            dspot = job.deadline_s  # on-demand VMs don't hibernate
 
-    if policy.use_burstables:
-        sol = burst_allocation(sol, job.tasks, cfg, dspot, job.deadline_s,
-                               params.burst_rate).solution
-    return PrimaryPlan(solution=sol, dspot=dspot, policy=policy)
+        if policy.primary == "ils":
+            if engine == "batched":
+                from .ils_jax import run_batched_ils
+                bp = batched_params if batched_params is not None \
+                    else _batched_params_from(params)
+                sol = run_batched_ils(job.tasks, pool, cfg, dspot,
+                                      job.deadline_s, bp,
+                                      market=policy.market).solution
+            elif engine == "exact":
+                sol = run_ils(job.tasks, pool, cfg, dspot, job.deadline_s,
+                              params, market=policy.market).solution
+            else:
+                raise ValueError(f"unknown ILS engine {engine!r} "
+                                 "(exact/batched)")
+        else:
+            sol = initial_solution(job.tasks, pool, cfg, dspot,
+                                   market=policy.market)
+            sol.selected_uids = set(sol.used_uids())
+
+        if policy.use_burstables:
+            sol = burst_allocation(sol, job.tasks, cfg, dspot, job.deadline_s,
+                                   params.burst_rate).solution
+        return PrimaryPlan(solution=sol, dspot=dspot, policy=policy)

@@ -5,6 +5,7 @@ from typing import Sequence
 
 import numpy as np
 
+from ..obs import span
 from .fitness import check_schedule
 from .types import (CloudConfig, ExecMode, Market, Solution, TaskSpec,
                     VMInstance, empty_solution)
@@ -43,58 +44,61 @@ def initial_solution(tasks: Sequence[TaskSpec], pool: list[VMInstance],
 
     ``market`` selects the candidate set: M^s (paper default) or M^o for the
     ILS-on-demand baseline of §IV."""
-    sol = empty_solution(len(tasks), pool)
-    market_uids = [vm.uid for vm in pool if vm.market == market]
-    free_by_type: dict[str, list[int]] = {}
-    for uid in market_uids:
-        free_by_type.setdefault(pool[uid].vm_type.name, []).append(uid)
+    with span("greedy.seed", n_tasks=len(tasks)):
+        sol = empty_solution(len(tasks), pool)
+        market_uids = [vm.uid for vm in pool if vm.market == market]
+        free_by_type: dict[str, list[int]] = {}
+        for uid in market_uids:
+            free_by_type.setdefault(pool[uid].vm_type.name, []).append(uid)
 
-    types = cfg.spot_types if market == Market.SPOT else cfg.ondemand_types
-    wrr = SmoothWRR([t.name for t in types],
-                    [t.weight(market) for t in types])
+        types = cfg.spot_types if market == Market.SPOT else cfg.ondemand_types
+        wrr = SmoothWRR([t.name for t in types],
+                        [t.weight(market) for t in types])
 
-    selected: list[int] = []          # uids, kept price-sorted on access
-    on_vm: dict[int, list[int]] = {}  # uid -> task indices
+        selected: list[int] = []          # uids, kept price-sorted on access
+        on_vm: dict[int, list[int]] = {}  # uid -> task indices
 
-    def _modes(uid: int) -> list[ExecMode]:
-        return [ExecMode.FULL] * len(on_vm.get(uid, []))
+        def _modes(uid: int) -> list[ExecMode]:
+            return [ExecMode.FULL] * len(on_vm.get(uid, []))
 
-    order = sorted(range(len(tasks)),
-                   key=lambda i: (-tasks[i].memory_mb, tasks[i].tid))
-    for i in order:
-        t = tasks[i]
-        placed = False
-        # Phase 1: already-selected VMs, cheapest first.
-        for uid in sorted(selected, key=lambda u: pool[u].price_per_sec):
-            cur = [tasks[k] for k in on_vm.get(uid, [])]
-            if check_schedule(t, pool[uid], cur, _modes(uid), cfg, dspot):
-                sol.alloc[i] = uid
-                on_vm.setdefault(uid, []).append(i)
-                placed = True
-                break
-        if placed:
-            continue
-        # Phase 2: open a new spot VM via WRR.
-        excluded: set[str] = set()  # types that cannot host this task at all
-        while True:
-            avail = {n for n, lst in free_by_type.items()
-                     if lst and n not in excluded}
-            tname = wrr.next(avail)
-            if tname is None:
-                raise RuntimeError(
-                    f"greedy: task {t.tid} cannot be scheduled within "
-                    f"D_spot={dspot:.0f}s — deadline too tight for the pool")
-            uid = free_by_type[tname].pop(0)
-            if check_schedule(t, pool[uid], [], [], cfg, dspot):
-                sol.alloc[i] = uid
-                on_vm[uid] = [i]
-                selected.append(uid)
-                placed = True
-                break
-            # Empty VM of this type cannot host the task: exclude the type
-            # for this task (put the instance back for later tasks).
-            free_by_type[tname].insert(0, uid)
-            excluded.add(tname)
+        order = sorted(range(len(tasks)),
+                       key=lambda i: (-tasks[i].memory_mb, tasks[i].tid))
+        for i in order:
+            t = tasks[i]
+            placed = False
+            # Phase 1: already-selected VMs, cheapest first.
+            for uid in sorted(selected, key=lambda u: pool[u].price_per_sec):
+                cur = [tasks[k] for k in on_vm.get(uid, [])]
+                if check_schedule(t, pool[uid], cur, _modes(uid), cfg, dspot):
+                    sol.alloc[i] = uid
+                    on_vm.setdefault(uid, []).append(i)
+                    placed = True
+                    break
+            if placed:
+                continue
+            # Phase 2: open a new spot VM via WRR.
+            # types that cannot host this task at all
+            excluded: set[str] = set()
+            while True:
+                avail = {n for n, lst in free_by_type.items()
+                         if lst and n not in excluded}
+                tname = wrr.next(avail)
+                if tname is None:
+                    raise RuntimeError(
+                        f"greedy: task {t.tid} cannot be scheduled within "
+                        f"D_spot={dspot:.0f}s — deadline too tight for the "
+                        "pool")
+                uid = free_by_type[tname].pop(0)
+                if check_schedule(t, pool[uid], [], [], cfg, dspot):
+                    sol.alloc[i] = uid
+                    on_vm[uid] = [i]
+                    selected.append(uid)
+                    placed = True
+                    break
+                # Empty VM of this type cannot host the task: exclude the type
+                # for this task (put the instance back for later tasks).
+                free_by_type[tname].insert(0, uid)
+                excluded.add(tname)
 
-    sol.selected_uids = set(selected)
-    return sol
+        sol.selected_uids = set(selected)
+        return sol

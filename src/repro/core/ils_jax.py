@@ -37,6 +37,7 @@ import numpy as np
 from repro.kernels.sched_fitness.ops import delta_fitness, population_fitness
 from repro.kernels.sched_fitness.ref import apply_moves
 from repro.kernels.sched_fitness.sched_fitness import population_reduce
+from ..obs import span
 from .fitness import cost_scale
 from .greedy import initial_solution
 from .types import (CloudConfig, Market, Solution, TaskSpec, VMInstance,
@@ -163,65 +164,69 @@ def run_batched_ils(tasks: Sequence[TaskSpec], pool: list[VMInstance],
     instead of the Alg. 2 greedy seed: chain 0 keeps the incumbent
     verbatim, chains 1..P-1 diversify from it — so a replan can only
     improve on the plan already running."""
-    rng = np.random.default_rng(params.seed)
-    e, rm, cores, mem, price, spot = _problem_arrays(tasks, pool, cfg)
-    scale = cost_scale(tasks, cfg)
-
     seed_sol = initial if initial is not None else \
         initial_solution(tasks, pool, cfg, dspot, market=market)
-    active = sorted(set(seed_sol.used_uids()) |
-                    {vm.uid for vm in pool if vm.market == market})
-    active_uids = jnp.asarray(active, jnp.int32)
-
     p = params.population
-    alloc0 = np.tile(seed_sol.alloc, (p, 1)).astype(np.int32)
-    # diversify chains 1..P-1 with random relocations
-    for i in range(1, p):
-        idx = rng.integers(0, len(tasks), size=max(1, len(tasks) // 10))
-        alloc0[i, idx] = rng.choice(active, size=len(idx))
-    alloc = jnp.asarray(alloc0)
+    with span("ils.prepare", population=p, n_tasks=len(tasks)):
+        rng = np.random.default_rng(params.seed)
+        e, rm, cores, mem, price, spot = _problem_arrays(tasks, pool, cfg)
+        scale = cost_scale(tasks, cfg)
+        active = sorted(set(seed_sol.used_uids()) |
+                        {vm.uid for vm in pool if vm.market == market})
+        active_uids = jnp.asarray(active, jnp.int32)
 
-    kw = dict(k=params.proposals, n=params.swap_tasks, dspot=dspot,
-              deadline=deadline, alpha=params.alpha, scale=scale,
-              boot_s=cfg.boot_overhead_s)
-    fit0, _, _ = population_fitness(
-        alloc, e, rm, cores, mem, price, spot, dspot=dspot,
-        deadline=deadline, alpha=params.alpha, cost_scale=scale,
-        boot_s=cfg.boot_overhead_s)
+        alloc0 = np.tile(seed_sol.alloc, (p, 1)).astype(np.int32)
+        # diversify chains 1..P-1 with random relocations
+        for i in range(1, p):
+            idx = rng.integers(0, len(tasks), size=max(1, len(tasks) // 10))
+            alloc0[i, idx] = rng.choice(active, size=len(idx))
+        alloc = jnp.asarray(alloc0)
+
+        kw = dict(k=params.proposals, n=params.swap_tasks, dspot=dspot,
+                  deadline=deadline, alpha=params.alpha, scale=scale,
+                  boot_s=cfg.boot_overhead_s)
+        fit0, _, _ = population_fitness(
+            alloc, e, rm, cores, mem, price, spot, dspot=dspot,
+            deadline=deadline, alpha=params.alpha, cost_scale=scale,
+            boot_s=cfg.boot_overhead_s)
 
     # per-iteration keys, derived identically for both engines
-    key = jax.random.PRNGKey(params.seed)
-    per_iter = []
-    for _ in range(params.iterations):
-        key, k1 = jax.random.split(key)
-        per_iter.append(k1)
-    keys = (jnp.stack(per_iter) if per_iter
-            else jnp.zeros((0,) + key.shape, key.dtype))
+    with span("ils.keys", iterations=params.iterations):
+        key = jax.random.PRNGKey(params.seed)
+        per_iter = []
+        for _ in range(params.iterations):
+            key, k1 = jax.random.split(key)
+            per_iter.append(k1)
+        keys = (jnp.stack(per_iter) if per_iter
+                else jnp.zeros((0,) + key.shape, key.dtype))
 
-    if params.engine == "scan":
-        scan_fn = _ils_scan(donate=jax.default_backend() != "cpu")
-        alloc, best_fit, hist = scan_fn(alloc, fit0, keys, active_uids,
-                                        e, rm, cores, mem, price, spot,
-                                        **kw)
-    elif params.engine == "step":
-        best_fit = fit0
-        hist = []
-        for i in range(params.iterations):
-            alloc, best_fit = _ils_step(alloc, best_fit, keys[i],
-                                        active_uids, e, rm, cores, mem,
-                                        price, spot, **kw)
-            hist.append(jnp.min(best_fit))   # device scalar — no host sync
-        hist = jnp.stack(hist) if hist else jnp.zeros((0,), jnp.float32)
-    else:
-        raise ValueError(f"unknown engine {params.engine!r} (scan/step)")
-    history = np.asarray(jax.device_get(hist))
+    # from the enqueue to the winner on the host
+    with span("ils.search", engine=params.engine):
+        if params.engine == "scan":
+            scan_fn = _ils_scan(donate=jax.default_backend() != "cpu")
+            alloc, best_fit, hist = scan_fn(alloc, fit0, keys, active_uids,
+                                            e, rm, cores, mem, price, spot,
+                                            **kw)
+        elif params.engine == "step":
+            best_fit = fit0
+            hist = []
+            for i in range(params.iterations):
+                alloc, best_fit = _ils_step(alloc, best_fit, keys[i],
+                                            active_uids, e, rm, cores, mem,
+                                            price, spot, **kw)
+                hist.append(jnp.min(best_fit))   # device scalar: no sync
+            hist = jnp.stack(hist) if hist else jnp.zeros((0,), jnp.float32)
+        else:
+            raise ValueError(f"unknown engine {params.engine!r} (scan/step)")
+        history = np.asarray(jax.device_get(hist))
 
-    win = int(jnp.argmin(best_fit))
-    sol = Solution(alloc=np.asarray(alloc[win]),
-                   modes=np.zeros(len(tasks), np.int8), pool=list(pool))
+        win = int(jnp.argmin(best_fit))
+        row = np.asarray(alloc[win])
+        fitness_bound = float(best_fit[win])
+
+    sol = Solution(alloc=row, modes=np.zeros(len(tasks), np.int8),
+                   pool=list(pool))
     sol.selected_uids = set(sol.used_uids())
     evals = p + params.population * params.proposals * params.iterations
-    return BatchedILSResult(solution=sol,
-                            fitness_bound=float(best_fit[win]),
-                            history=history,
-                            evaluations=evals)
+    return BatchedILSResult(solution=sol, fitness_bound=fitness_bound,
+                            history=history, evaluations=evals)

@@ -58,8 +58,9 @@ nothing: ``DEADLINE_MISSED`` retires the task, ``CONGESTION`` retries
 at the next boundary.
 
 First-class service metrics (``ServiceResult.summary``): sustained
-tasks/s admitted, SLO-met fraction and replan-latency p95 — fed into
-BENCH_dynamic.json via ``benchmarks/service_bench.py``.
+tasks/s admitted, SLO-met fraction and replan-latency p95, printed by
+``benchmarks/service_bench.py``.  Its chip benchmark is a future cell of
+``bench/run.py`` (PERF.md §7).
 """
 from __future__ import annotations
 
