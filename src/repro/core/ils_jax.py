@@ -134,6 +134,19 @@ def _ils_scan_impl(alloc, best_fit, keys, active_uids, e, rm, cores, mem,
     return alloc, best_fit, hist
 
 
+@functools.partial(jax.jit, static_argnames=("iterations",))
+def _iteration_keys(key, iterations: int):
+    """The engines' per-iteration keys, ``[iterations, 2]`` uint32: the
+    chain ``key, k1 = split(key)`` as one program (one compile per
+    ``iterations``) rather than one eager dispatch per split."""
+    def step(key, _):
+        key, k1 = jax.random.split(key)
+        return key, k1
+
+    _, keys = jax.lax.scan(step, key, None, length=iterations)
+    return keys
+
+
 @functools.lru_cache(maxsize=2)
 def _ils_scan(donate: bool):
     """jit the scan engine, donating the alloc/best_fit carry buffers on
@@ -192,13 +205,8 @@ def run_batched_ils(tasks: Sequence[TaskSpec], pool: list[VMInstance],
 
     # per-iteration keys, derived identically for both engines
     with span("ils.keys", iterations=params.iterations):
-        key = jax.random.PRNGKey(params.seed)
-        per_iter = []
-        for _ in range(params.iterations):
-            key, k1 = jax.random.split(key)
-            per_iter.append(k1)
-        keys = (jnp.stack(per_iter) if per_iter
-                else jnp.zeros((0,) + key.shape, key.dtype))
+        keys = _iteration_keys(jax.random.PRNGKey(params.seed),
+                               iterations=params.iterations)
 
     # from the enqueue to the winner on the host
     with span("ils.search", engine=params.engine):
@@ -218,10 +226,10 @@ def run_batched_ils(tasks: Sequence[TaskSpec], pool: list[VMInstance],
             hist = jnp.stack(hist) if hist else jnp.zeros((0,), jnp.float32)
         else:
             raise ValueError(f"unknown engine {params.engine!r} (scan/step)")
-        history = np.asarray(jax.device_get(hist))
-
-        win = int(jnp.argmin(best_fit))
-        row = np.asarray(alloc[win])
+        # one transfer; numpy's argmin takes the first minimum, as jnp's
+        history, alloc, best_fit = jax.device_get((hist, alloc, best_fit))
+        win = int(np.argmin(best_fit))
+        row = np.array(alloc[win])
         fitness_bound = float(best_fit[win])
 
     sol = Solution(alloc=row, modes=np.zeros(len(tasks), np.int8),

@@ -7,9 +7,12 @@ engines must walk the same search trajectory.
 Problems are built directly from TaskSpec (not make_job) so instances are
 identical across processes.
 """
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import ils_jax
 from repro.core.dspot import compute_dspot
 from repro.core.evaluator import CachedEvaluator
 from repro.core.ils_jax import BatchedILSParams, run_batched_ils
@@ -87,3 +90,69 @@ def test_zero_iterations_returns_seed_population_best(problem, engine):
     res = _run(engine, tasks, dspot, iterations=0)
     assert res.history.shape == (0,)
     assert np.isfinite(res.fitness_bound)
+
+
+def _eager_keys(key, iterations):
+    """The per-iteration key chain as eager dispatches, one split each."""
+    per_iter = []
+    for _ in range(iterations):
+        key, k1 = jax.random.split(key)
+        per_iter.append(k1)
+    return (jnp.stack(per_iter) if per_iter
+            else jnp.zeros((0,) + key.shape, key.dtype))
+
+
+@pytest.mark.parametrize("iterations", [0, 1, 200])
+@pytest.mark.parametrize("seed", [0, 3, 2**31 - 1])
+def test_iteration_keys_match_the_eager_split_chain(seed, iterations):
+    key = jax.random.PRNGKey(seed)
+    got = np.asarray(ils_jax._iteration_keys(key, iterations=iterations))
+    want = np.asarray(_eager_keys(key, iterations))
+    assert got.shape == (iterations, 2) and got.dtype == np.uint32
+    np.testing.assert_array_equal(got, want)
+
+
+def test_iteration_keys_compile_once_per_iteration_count(problem):
+    tasks, dspot = problem
+    ils_jax._iteration_keys.clear_cache()
+    _run("scan", tasks, dspot, iterations=5)
+    _run("scan", tasks, dspot, iterations=5, seed=1)
+    assert ils_jax._iteration_keys._cache_size() == 1
+
+
+@pytest.mark.parametrize("engine", ["scan", "step"])
+def test_one_key_program_and_host_winner_change_no_result(problem, engine,
+                                                          monkeypatch):
+    """The jitted key chain and the host argmin give what the eager split
+    chain and the device argmin, row and fitness gave."""
+    tasks, dspot = problem
+    new = _run(engine, tasks, dspot)
+
+    finals = []
+    if engine == "scan":
+        scan = ils_jax._ils_scan
+
+        def recording(donate):
+            def run(*args, **kw):
+                out = scan(False)(*args, **kw)
+                finals.append(out[:2])
+                return out
+            return run
+        monkeypatch.setattr(ils_jax, "_ils_scan", recording)
+    else:
+        step = ils_jax._ils_step
+
+        def recording(*args, **kw):
+            out = step(*args, **kw)
+            finals.append(out)
+            return out
+        monkeypatch.setattr(ils_jax, "_ils_step", recording)
+    monkeypatch.setattr(ils_jax, "_iteration_keys", _eager_keys)
+    old = _run(engine, tasks, dspot)
+
+    alloc, best_fit = finals[-1]
+    win = int(jnp.argmin(best_fit))
+    np.testing.assert_array_equal(new.history, old.history)
+    np.testing.assert_array_equal(new.solution.alloc, np.asarray(alloc[win]))
+    np.testing.assert_array_equal(new.solution.alloc, old.solution.alloc)
+    assert new.fitness_bound == float(best_fit[win]) == old.fitness_bound
