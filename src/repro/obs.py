@@ -1,4 +1,5 @@
-"""Program spans: the host phases of a plan, written into the profiler's trace.
+"""Program spans: the host phases of a plan and of an engine run, written
+into the profiler's trace.
 
 ``span(name, **args)`` is a ``jax.profiler.TraceAnnotation``.  While a
 profiler trace runs, it records the phase on the trace's clock, beside
@@ -13,14 +14,18 @@ from __future__ import annotations
 
 import jax
 
-#: every span name the program emits; ``plan`` holds the others
+#: the spans of a plan; ``plan`` holds the others
 SPANS = ("plan", "greedy.seed", "ils.prepare", "ils.keys", "ils.search",
          "burst.alloc")
+#: the spans of the Monte-Carlo engine: ``mc.run`` wraps one
+#: ``run_mc_events`` call, from its checks to the fetch of its outputs
+ENGINE_SPANS = ("mc.run",)
+_NAMES = SPANS + ENGINE_SPANS
 
 
 def span(name: str, **args) -> jax.profiler.TraceAnnotation:
-    """The span ``name`` (one of ``SPANS``), carrying ``args``; use it as
-    a context manager."""
-    if name not in SPANS:
-        raise ValueError(f"unknown span {name!r} (one of {SPANS})")
+    """The span ``name`` (one of ``SPANS`` or ``ENGINE_SPANS``), carrying
+    ``args``; use it as a context manager."""
+    if name not in _NAMES:
+        raise ValueError(f"unknown span {name!r} (one of {_NAMES})")
     return jax.profiler.TraceAnnotation(name, **args)

@@ -410,8 +410,6 @@ class Service:
             "spot": jnp.asarray(self._spot),
             "burst": jnp.asarray(self._burst),
             "odm": jnp.asarray(self._odm),
-            "burst_idx": jnp.asarray(np.flatnonzero(self._burst),
-                                     jnp.int32),
             "launched0": jnp.zeros(self.n_vms, bool),
         }
 

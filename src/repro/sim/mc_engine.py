@@ -94,6 +94,7 @@ from repro.core.runtime import CHECKPOINT_WRITE_S
 from repro.core.types import CloudConfig, Job, Market
 from repro.ft.checkpoint import checkpoint_schedule
 from repro.kernels.sched_fitness.ops import mc_span_advance, mc_vm_stats
+from repro.obs import span
 from .events import SC_NONE, Scenario
 from .market import EventTensor, MarketProcess, as_process
 
@@ -102,6 +103,12 @@ HIGHEST = jax.lax.Precision.HIGHEST
 
 #: VM column lifecycle codes (``vstate``)
 NOT_LAUNCHED, VM_ACTIVE, VM_HIBERNATED, VM_TERMINATED = 0, 1, 2, 3
+
+#: the while-body's batch-wide ``lax.cond`` branches, in the order of the
+#: loop-carried entry counters (``MCResult.branch_entries``): spot
+#: termination with Alg. 4, hibernation with Alg. 4, deferred-HADS
+#: migration, the Allocation-Cycle block, the orphan retry
+BRANCHES = ("terminate", "hibernate", "defer", "ac", "retry")
 
 
 def dist_stats(x: np.ndarray) -> dict:
@@ -333,6 +340,10 @@ class MCResult:
     n_orphans: np.ndarray | None = None   # int [S] stranded at exit
     retry_rounds: np.ndarray | None = None  # int [S] successful retries
     n_done: np.ndarray | None = None      # int [S] completed tasks
+    #: loop iterations that entered each batch-wide branch of the
+    #: while-body (``BRANCHES`` -> count; summed over the devices of a
+    #: sharded run).  Bookkeeping only: no other output depends on it.
+    branch_entries: dict | None = None
 
     @property
     def n(self) -> int:
@@ -442,8 +453,6 @@ def _plan_arrays(job: Job, plan: PrimaryPlan, cfg: CloudConfig, ovh: float
         "burst": jnp.asarray([vm.is_burstable for vm in vms], bool),
         "odm": jnp.asarray([vm.market == Market.ONDEMAND for vm in vms],
                            bool),
-        "burst_idx": jnp.asarray(
-            [c for c, vm in enumerate(vms) if vm.is_burstable], jnp.int32),
         "launched0": jnp.asarray([u in sol.selected_uids for u in uids],
                                  bool),
     }
@@ -680,7 +689,6 @@ def _mc_run_impl(arr: dict, sc: dict, ev: EventTensor,
     dt = sc["dt"]
     iota_v = jnp.arange(v)[None]
     rows = jnp.arange(s)
-    bi = arr["burst_idx"]
     adaptive = stepping == "adaptive"
     # trace-time gate: a termination-free tensor (term_k is None) compiles
     # to exactly the historical pre-termination program (§2.8)
@@ -755,6 +763,11 @@ def _mc_run_impl(arr: dict, sc: dict, ev: EventTensor,
                 jnp.asarray(state.orph, bool) if state.orph is not None
                 else jnp.zeros((s, b), bool),                     # orph
                 jnp.zeros(s, jnp.int32))                          # oret
+    # entries into each batch-wide branch (``BRANCHES``), last in the carry
+    carry = carry + (jnp.zeros(len(BRANCHES), jnp.int32),)
+
+    def entered(ctr, branch, pred):
+        return ctr.at[BRANCHES.index(branch)].add(pred.astype(jnp.int32))
 
     def cond(c):
         # a scenario is live while it has pending work inside the horizon
@@ -767,6 +780,7 @@ def _mc_run_impl(arr: dict, sc: dict, ev: EventTensor,
          nhib, nres, nterm, nsteps, visited) = c[:14]
         if track_orph:
             orph, oret = c[14], c[15]
+        ctr = c[-1]
 
         pending = rem > 0.0
         # a row is live while it has pending work *inside* the horizon
@@ -777,111 +791,110 @@ def _mc_run_impl(arr: dict, sc: dict, ev: EventTensor,
         in_h = i < stop
         gate = jnp.any(pending, axis=1) & in_h                # [S] live
 
-        # ---- per-step stats: the hot [S, B] -> [S, V] reduction ---------
-        # One shared pending one-hot feeds every column reduction; its
-        # task-axis cumsum yields both per-column counts and each task's
-        # queue rank within its column (B-axis order = dispatch priority).
-        # All of it is span-invariant — spans are completion/event-free by
-        # construction (DESIGN.md §2.5) — so one computation serves the
-        # span jump *and* the full step that follows it.
-        ohp = ((assign[:, :, None] == iota_v[None]) &
-               pending[:, :, None]).astype(jnp.float32)       # [S, B, V]
-        cum = jnp.cumsum(ohp, axis=1)
-        cnt = cum[:, -1, :]
-        # burstable-column view: every credit op below runs on the static
-        # column subset ``bi`` — in row-parametric mode that is the
-        # *union* of the fused plans' burstable positions (a non-burst
-        # column there has crate = ccap = 0 and can neither accrue nor
-        # bound anything, so the union loses no information and keeps
-        # the per-iteration credit work O(K), not O(V))
-        ohb = ohp[:, :, bi]
+        with jax.named_scope("mc.stats"):
+            # ---- per-step stats: the hot [S, B] -> [S, V] reduction ---------
+            # One shared pending one-hot feeds every column reduction; its
+            # task-axis cumsum yields both per-column counts and each task's
+            # queue rank within its column (B-axis order = dispatch priority).
+            # All of it is span-invariant — spans are completion/event-free
+            # by construction (DESIGN.md §2.5) — so one computation serves
+            # the span jump *and* the full step that follows it.
+            ohp = ((assign[:, :, None] == iota_v[None]) &
+                   pending[:, :, None]).astype(jnp.float32)       # [S, B, V]
+            cum = jnp.cumsum(ohp, axis=1)
+            cnt = cum[:, -1, :]
 
-        def col_sum(w):
-            """Per-column sum of the [S, B] weight vector ``w`` (f32 on
-            every backend: a TPU's default dot precision is bf16)."""
-            return jnp.einsum("sbv,sb->sv", ohp, w, precision=HIGHEST)
+            def col_sum(w):
+                """Per-column sum of the [S, B] weight vector ``w`` (f32 on
+                every backend: a TPU's default dot precision is bf16)."""
+                return jnp.einsum("sbv,sb->sv", ohp, w, precision=HIGHEST)
 
-        rank = jnp.take_along_axis(cum, assign[:, :, None],
-                                   axis=2)[:, :, 0] - 1.0
-        burst_t = gv(burst, assign)
-        run0 = pending & (rank < gv(cores, assign))
-        if not mem_safe:
-            memcum = jnp.take_along_axis(
-                jnp.cumsum(ohp * (mem_t[:, :, None] if rowp
-                                  else mem_t[None, :, None]), axis=1),
-                assign[:, :, None], axis=2)[:, :, 0]
-            run0 &= memcum <= gv(memv, assign) + 1e-6
+            rank = jnp.take_along_axis(cum, assign[:, :, None],
+                                       axis=2)[:, :, 0] - 1.0
+            burst_t = gv(burst, assign)
+            run0 = pending & (rank < gv(cores, assign))
+            if not mem_safe:
+                memcum = jnp.take_along_axis(
+                    jnp.cumsum(ohp * (mem_t[:, :, None] if rowp
+                                      else mem_t[None, :, None]), axis=1),
+                    assign[:, :, None], axis=2)[:, :, 0]
+                run0 &= memcum <= gv(memv, assign) + 1e-6
 
-        cap = ccap[:, bi] if rowp else ccap[bi][None]
-        crate_b = crate[:, bi] if rowp else crate[bi][None]
+            # the credit buckets run full width, masked to the burstable
+            # columns (a non-burst column has crate = ccap = 0).  Reading
+            # and writing them through a traced list of burstable columns
+            # (gather + scatter) computed wrong buckets inside this loop on
+            # the TPU v5e: a bucket read 1.3 credits from its first booted
+            # slot on and never moved again (PERF.md §6)
+            burst_v, cap, crate_v = bc(burst), bc(ccap), bc(crate)
 
-        if adaptive:
-            # ============================================================
-            # Event-horizon jump (DESIGN.md §2.5): per scenario, find the
-            # largest span of *uniform* slots — no tensor event, no AC
-            # boundary, no task completion, no boot edge, no
-            # credit-bucket boundary, no deferred-HADS fire instant — and
-            # advance it in closed form.  Scenarios step their own clocks
-            # (``i`` is [S]): a storm in one scenario never forces the
-            # calm ones to slot-crawl, so iterations track the *worst*
-            # scenario's interesting-slot count, not the batch union.
-            # Each bound below is the first non-uniform slot offset (or
-            # BIG); the multiplicative backoff on the float-derived
-            # bounds (x * (1 - 1e-6), >= 10x the accumulated rounding
-            # error of the divisions producing x) keeps a span from ever
-            # overshooting into the non-uniform region when a ratio
-            # rounds up across an integer, without paying a systematic
-            # one-slot creep at every boundary.
-            # ============================================================
-            BACK = 1.0 - 1e-6
-            t0 = i.astype(jnp.float32) * dt
-            active0 = vstate == VM_ACTIVE
-            live01 = (active0 & (boot <= t0[:, None])).astype(jnp.float32)
-            rate0 = jnp.take_along_axis(live01, assign, axis=1)
-            cred_ok0 = jnp.take_along_axis(credits > 1e-9, assign, axis=1)
-            sfac0 = jnp.where((mode == 1) | (burst_t & ~cred_ok0),
-                              gv(bfrac, assign), 1.0)
-            drem0 = dt * rate0 * gv(speed, assign) * sfac0 * run0
-            spend0 = jnp.einsum("sbk,sb->sk", ohb,
-                                (run0 & (mode == 0)).astype(jnp.float32))
+        with jax.named_scope("mc.span_advance"):
+            if adaptive:
+                # ============================================================
+                # Event-horizon jump (DESIGN.md §2.5): per scenario, find the
+                # largest span of *uniform* slots — no tensor event, no AC
+                # boundary, no task completion, no boot edge, no
+                # credit-bucket boundary, no deferred-HADS fire instant — and
+                # advance it in closed form.  Scenarios step their own clocks
+                # (``i`` is [S]): a storm in one scenario never forces the
+                # calm ones to slot-crawl, so iterations track the *worst*
+                # scenario's interesting-slot count, not the batch union.
+                # Each bound below is the first non-uniform slot offset (or
+                # BIG); the multiplicative backoff on the float-derived
+                # bounds (x * (1 - 1e-6), >= 10x the accumulated rounding
+                # error of the divisions producing x) keeps a span from ever
+                # overshooting into the non-uniform region when a ratio
+                # rounds up across an integer, without paying a systematic
+                # one-slot creep at every boundary.
+                # ============================================================
+                BACK = 1.0 - 1e-6
+                t0 = i.astype(jnp.float32) * dt
+                active0 = vstate == VM_ACTIVE
+                live01 = (active0 & (boot <= t0[:, None])).astype(jnp.float32)
+                rate0 = jnp.take_along_axis(live01, assign, axis=1)
+                cred_ok0 = jnp.take_along_axis(credits > 1e-9, assign, axis=1)
+                sfac0 = jnp.where((mode == 1) | (burst_t & ~cred_ok0),
+                                  gv(bfrac, assign), 1.0)
+                drem0 = dt * rate0 * gv(speed, assign) * sfac0 * run0
+                spend0 = jnp.einsum("sbv,sb->sv", ohp,
+                                    (run0 & (mode == 0)).astype(jnp.float32))
 
-            # (1) next nonzero event slot, O(1) from the per-scenario
-            # tensor pointer (EventTensor.nxt, built at generation time);
-            # the pointer is tensor-relative — shift by slot0
-            it = i - slot0
-            m_ev = (ev.nxt[rows, jnp.minimum(it, n_slots - 1)] - it
-                    ).astype(jnp.float32)
-            # (2) next AC boundary (edge e is handled by the step at e-1)
-            if ac_aligned:
-                base, ac = sc["boot_slots"], sc["ac_slots"]
-                q = jnp.maximum(i + 1 - base, 1)
-                e = base + ac * ((q + ac - 1) // ac)
-                m_ac = (e - 1 - i).astype(jnp.float32)
-            else:
-                k_next = jnp.maximum(
-                    jnp.floor((t0 - sc["omega"]) / sc["ac_seconds"]),
-                    0.0) + 1.0
-                e_t = sc["omega"] + sc["ac_seconds"] * k_next
-                m_ac = jnp.maximum(
-                    jnp.ceil(e_t / dt * BACK) - 1.0
-                    - i.astype(jnp.float32), 0.0)
-            # (3) first task completion among running tasks
-            ratio = jnp.where(drem0 > 0.0,
-                              rem / jnp.maximum(drem0, 1e-30), BIG)
-            m_comp = jnp.maximum(
-                jnp.min(jnp.ceil(ratio * BACK), axis=1) - 1.0, 0.0)
-            # (4) boot edges of still-booting active columns
-            kb = jnp.where(active0 & (boot > t0[:, None]),
-                           jnp.floor((boot - t0[:, None]) / dt * BACK), BIG)
-            m_boot = jnp.maximum(jnp.min(kb, axis=1), 0.0)
-            # (5) burstable credit boundaries: a bucket emptying (speed
-            # factor flips), refilling from empty, or reaching cap —
-            # between them the buckets are piecewise linear
-            r_c = dt * live01[:, bi] * crate_b \
-                - (dt / sc["bperiod"]) * spend0
-            c0 = credits[:, bi]
-            act_b = active0[:, bi]
-            if bi.shape[0]:                # plans without burstables skip
+                # (1) next nonzero event slot, O(1) from the per-scenario
+                # tensor pointer (EventTensor.nxt, built at generation time);
+                # the pointer is tensor-relative — shift by slot0
+                it = i - slot0
+                m_ev = (ev.nxt[rows, jnp.minimum(it, n_slots - 1)] - it
+                        ).astype(jnp.float32)
+                # (2) next AC boundary (edge e is handled by the step at e-1)
+                if ac_aligned:
+                    base, ac = sc["boot_slots"], sc["ac_slots"]
+                    q = jnp.maximum(i + 1 - base, 1)
+                    e = base + ac * ((q + ac - 1) // ac)
+                    m_ac = (e - 1 - i).astype(jnp.float32)
+                else:
+                    k_next = jnp.maximum(
+                        jnp.floor((t0 - sc["omega"]) / sc["ac_seconds"]),
+                        0.0) + 1.0
+                    e_t = sc["omega"] + sc["ac_seconds"] * k_next
+                    m_ac = jnp.maximum(
+                        jnp.ceil(e_t / dt * BACK) - 1.0
+                        - i.astype(jnp.float32), 0.0)
+                # (3) first task completion among running tasks
+                ratio = jnp.where(drem0 > 0.0,
+                                  rem / jnp.maximum(drem0, 1e-30), BIG)
+                m_comp = jnp.maximum(
+                    jnp.min(jnp.ceil(ratio * BACK), axis=1) - 1.0, 0.0)
+                # (4) boot edges of still-booting active columns
+                kb = jnp.where(active0 & (boot > t0[:, None]),
+                               jnp.floor((boot - t0[:, None]) / dt * BACK),
+                               BIG)
+                m_boot = jnp.maximum(jnp.min(kb, axis=1), 0.0)
+                # (5) burstable credit boundaries: a bucket emptying (speed
+                # factor flips), refilling from empty, or reaching cap —
+                # between them the buckets are piecewise linear
+                r_c = dt * live01 * crate_v - (dt / sc["bperiod"]) * spend0
+                c0 = credits
+                act_b = active0 & burst_v
                 rising = act_b & (r_c > 1e-12)
                 kc = jnp.full_like(r_c, BIG)
                 kc = jnp.where(rising & (c0 <= 1e-9), 1.0, kc)
@@ -894,354 +907,367 @@ def _mc_run_impl(arr: dict, sc: dict, ev: EventTensor,
                                    jnp.ceil((c0 - 1e-9) / (-r_c) * BACK),
                                    1.0), kc)
                 m_cred = jnp.min(kc, axis=1)
-            else:
-                m_cred = jnp.full(s, BIG, jnp.float32)
-            # (6) deferred-HADS fire instant — frozen columns' max
-            # remaining work is span-invariant, so t_safe is a fixed time
-            # (pure-freeze policies never fire: resume is their only out)
-            if policy.deferred_migration:
-                maxw0 = jnp.max(ohp * rem[:, :, None], axis=1)
-                t_safe0 = dl2 - (
-                    sc["omega"] + maxw0 / sc["od_speed"] + sc["restore"]
-                    + sc["margin"])
-                kf = jnp.where((vstate == VM_HIBERNATED) & (cnt > 0.5),
-                               jnp.floor((t_safe0 - t0[:, None]) / dt - 2.0),
-                               BIG)
-                m_fire = jnp.maximum(jnp.min(kf, axis=1), 0.0)
-            else:
-                m_fire = jnp.full(s, BIG, jnp.float32)
+                # (6) deferred-HADS fire instant — frozen columns' max
+                # remaining work is span-invariant, so t_safe is a fixed time
+                # (pure-freeze policies never fire: resume is their only out)
+                if policy.deferred_migration:
+                    maxw0 = jnp.max(ohp * rem[:, :, None], axis=1)
+                    t_safe0 = dl2 - (
+                        sc["omega"] + maxw0 / sc["od_speed"] + sc["restore"]
+                        + sc["margin"])
+                    kf = jnp.where((vstate == VM_HIBERNATED) & (cnt > 0.5),
+                                   jnp.floor((t_safe0 - t0[:, None]) / dt
+                                             - 2.0),
+                                   BIG)
+                    m_fire = jnp.maximum(jnp.min(kf, axis=1), 0.0)
+                else:
+                    m_fire = jnp.full(s, BIG, jnp.float32)
 
-            # finished scenarios have no bounds left — they jump straight
-            # to their exit slot (horizon or stop boundary)
-            m_max = jnp.maximum(stop - 1 - i, 0).astype(jnp.float32)
-            bounds = jnp.stack([m_ev, m_ac, m_comp, m_boot, m_cred,
-                                m_fire])                     # [6, S]
-            mf = jnp.clip(jnp.where(gate, jnp.min(bounds, axis=0), BIG),
-                          0.0, m_max)
-            m = mf.astype(jnp.int32)
-            mf = m.astype(jnp.float32)
+                # finished scenarios have no bounds left — they jump straight
+                # to their exit slot (horizon or stop boundary)
+                m_max = jnp.maximum(stop - 1 - i, 0).astype(jnp.float32)
+                bounds = jnp.stack([m_ev, m_ac, m_comp, m_boot, m_cred,
+                                    m_fire])                     # [6, S]
+                mf = jnp.clip(jnp.where(gate, jnp.min(bounds, axis=0), BIG),
+                              0.0, m_max)
+                m = mf.astype(jnp.int32)
+                mf = m.astype(jnp.float32)
 
-            # ---- closed-form span advance: m uniform slots at once -----
-            if use_kernel:
-                # fused Pallas kernel: progress decrement + the [S, V]
-                # reductions of the advanced state in one streamed pass
-                rem, _, cnt, maxw = mc_span_advance(
-                    assign, rem, drem0, mf, v=v)
+                # ---- closed-form span advance: m uniform slots at once -----
+                if use_kernel:
+                    # fused Pallas kernel: progress decrement + the [S, V]
+                    # reductions of the advanced state in one streamed pass
+                    rem, _, cnt, maxw = mc_span_advance(
+                        assign, rem, drem0, mf, v=v)
+                else:
+                    rem = jnp.where(pending,
+                                    jnp.maximum(rem - mf[:, None] * drem0,
+                                                0.0),
+                                    rem)
+                    # recompute from the advanced state: a column hibernated
+                    # by *this* step's events needs its post-span max (the
+                    # m_fire bound above could reuse maxw0 only because it
+                    # reads already-hibernated, hence frozen, columns)
+                    maxw = jnp.max(ohp * rem[:, :, None], axis=1) \
+                        if policy.deferred_migration else None
+                billed = billed + mf[:, None] * dt * live01 * gate[:, None]
+                credits = jnp.where(
+                    act_b, jnp.clip(c0 + mf[:, None] * r_c, 0.0, cap), c0)
+                i = i + m
+            elif use_kernel:
+                # accelerator path: the Pallas kernel supplies the [S, V]
+                # reductions — counts/max here, migration loads post-progress
+                # inside the event branches.  The one-hot/cumsum above remains
+                # only for the queue rank; a TPU-native rank kernel is the
+                # open item (DESIGN.md §2.3).
+                _, cnt, maxw = mc_vm_stats(assign, rem, v=v)
             else:
-                rem = jnp.where(pending,
-                                jnp.maximum(rem - mf[:, None] * drem0, 0.0),
-                                rem)
-                # recompute from the advanced state: a column hibernated
-                # by *this* step's events needs its post-span max (the
-                # m_fire bound above could reuse maxw0 only because it
-                # reads already-hibernated, hence frozen, columns)
                 maxw = jnp.max(ohp * rem[:, :, None], axis=1) \
                     if policy.deferred_migration else None
-            billed = billed + mf[:, None] * dt * live01 * gate[:, None]
-            span_cred = jnp.where(
-                act_b, jnp.clip(c0 + mf[:, None] * r_c, 0.0, cap), c0)
-            credits = credits.at[:, bi].set(span_cred)
-            i = i + m
-        elif use_kernel:
-            # accelerator path: the Pallas kernel supplies the [S, V]
-            # reductions — counts/max here, migration loads post-progress
-            # inside the event branches.  The one-hot/cumsum above remains
-            # only for the queue rank; a TPU-native rank kernel is the
-            # open item (DESIGN.md §2.3).
-            _, cnt, maxw = mc_vm_stats(assign, rem, v=v)
-        else:
-            maxw = jnp.max(ohp * rem[:, :, None], axis=1) \
-                if policy.deferred_migration else None
 
         # ================================================================
         # Full step at slot i (per-scenario) — under "slot" stepping
         # every slot lands here; under "adaptive" only interesting ones.
         # ================================================================
-        t = i.astype(jnp.float32) * dt     # [S]; slot covers [t, t + dt)
-        t1 = t + dt
-        # this slot's pregenerated market events (DESIGN.md §2.4)
-        if adaptive:
-            # scenarios sit on different slots: per-row gather
-            # (tensor-relative index — the tensor is anchored at slot0)
-            ir = jnp.minimum(i - slot0, n_slots - 1)
-            hib_k, hib_u = ev.hib_k[rows, ir], ev.hib_u[rows, ir]
-            res_k, res_u = ev.res_k[rows, ir], ev.res_u[rows, ir]
-            if has_term:
-                term_k, term_u = ev.term_k[rows, ir], ev.term_u[rows, ir]
-        else:
-            # lockstep slot walk: one dynamic slice, as before
-            i0 = i[0] - slot0
-            hib_k = jax.lax.dynamic_index_in_dim(ev.hib_k, i0, 1,
-                                                 keepdims=False)
-            hib_u = jax.lax.dynamic_index_in_dim(ev.hib_u, i0, 1,
-                                                 keepdims=False)
-            res_k = jax.lax.dynamic_index_in_dim(ev.res_k, i0, 1,
-                                                 keepdims=False)
-            res_u = jax.lax.dynamic_index_in_dim(ev.res_u, i0, 1,
-                                                 keepdims=False)
-            if has_term:
-                term_k = jax.lax.dynamic_index_in_dim(ev.term_k, i0, 1,
-                                                      keepdims=False)
-                term_u = jax.lax.dynamic_index_in_dim(ev.term_u, i0, 1,
-                                                      keepdims=False)
-
-        # ---- progress over [t, t + dt) ----------------------------------
-        active = vstate == VM_ACTIVE
-        live = jnp.clip((t1[:, None] - boot) / dt, 0.0, 1.0) * active \
-            * in_h[:, None]
-        rate_t = jnp.take_along_axis(live, assign, axis=1)
-        cred_ok = jnp.take_along_axis(credits > 1e-9, assign, axis=1)
-        sfac = jnp.where((mode == 1) | (burst_t & ~cred_ok),
-                         gv(bfrac, assign), 1.0)
-        run = run0
-        drem = dt * rate_t * gv(speed, assign) * sfac * run
-        rem2 = jnp.maximum(rem - drem, 0.0)
-        newly = pending & (rem2 <= 0.0)
-        frac = jnp.clip(rem / jnp.maximum(drem, 1e-9), 0.0, 1.0)
-        done_at = jnp.where(newly, t[:, None] + dt * frac, done_at)
-
-        # ---- billing (pauses during hibernation, ends at termination /
-        # scenario completion) + burstable credit accrual -----------------
-        billed = billed + dt * live * gate[:, None]
-        spend_b = jnp.einsum("sbk,sb->sk", ohb,
-                             (run & (mode == 0)).astype(jnp.float32))
-        credits = credits.at[:, bi].set(jnp.where(
-            active[:, bi],
-            jnp.clip(credits[:, bi] + dt * live[:, bi] * crate_b
-                     - (dt / sc["bperiod"]) * spend_b, 0.0, cap),
-            credits[:, bi]))
-
-        rcv = jnp.zeros((s, v), bool)      # columns given tasks this slot
-
-        # victims for both loss events: active, booted, spot columns
-        hib_elig = active & bc(spot) & (boot <= t1[:, None])
-
-        # ---- terminate events (§2.8): the provider reclaims the column —
-        # state lost.  Resolved BEFORE hibernation (terminate wins slot
-        # collisions) over the same eligible set; a terminated column is
-        # then excluded from this slot's hibernation picks.  Billing stops
-        # structurally: live requires VM_ACTIVE, and resume eligibility is
-        # VM_HIBERNATED, so a terminated column never bills or revives.
-        # Unfinished tasks roll back to the checkpoint floor and ALWAYS
-        # re-enter Alg. 4 migration — with memory lost there is no state
-        # to freeze in place, whatever the hibernation axis says.
-        if has_term:
-            trm = _select(term_u, hib_elig, term_k) & gate[:, None]
-            do_trm = jnp.any(trm, axis=1)
-            nterm = nterm + jnp.sum(trm, axis=1)
-            vstate = jnp.where(trm, VM_TERMINATED, vstate)
-            hib_elig = hib_elig & ~trm
-            aff_t = jnp.take_along_axis(trm, assign, axis=1) & (rem2 > 0)
-
-            def migt(ops):
-                rem2, assign, mode, vstate, boot, rcv, orph = ops
-                load = mc_vm_stats(assign, rem2, v=v)[0] \
-                    if use_kernel else col_sum(rem2 * (rem2 > 0))
-                (rem2, assign, mode, vstate, boot, rcv,
-                 moved) = _migrate_spread(
-                    do_trm, aff_t, rem2, load, vstate, boot, credits,
-                    assign, mode, rcv, arr, sc, t1,
-                    allow_burstable=policy.use_burstables,
-                    rounds=mig_rounds, track_moved=True,
-                    cascade=dest_cascade, dl2=dl2)
-                # ledger (§2.10): an affected task no round re-placed is
-                # stranded on its (now dead) column — record it for the
-                # retry pass below / service re-admission
-                orph = orph | (aff_t & ~moved)
-                return rem2, assign, mode, vstate, boot, rcv, orph
-
-            (rem2, assign, mode, vstate, boot, rcv, orph) = jax.lax.cond(
-                jnp.any(aff_t), migt, lambda ops: ops,
-                (rem2, assign, mode, vstate, boot, rcv, orph))
-
-        # ---- hibernation events (victims: requested count resolved
-        # against the live eligible set — active, booted, spot) -----------
-        hib = _select(hib_u, hib_elig, hib_k) & \
-            gate[:, None]
-        do_hib = jnp.any(hib, axis=1)
-        nhib = nhib + jnp.sum(hib, axis=1)
-        vstate = jnp.where(hib, VM_HIBERNATED, vstate)
-
-        if policy.immediate_migration:
-            # Alg. 4: checkpoint rollback + spread argmin re-assignment
-            affected = jnp.take_along_axis(hib, assign, axis=1) & \
-                (rem2 > 0)
-
-            def mig(ops):
-                if track_orph:
-                    rem2, assign, mode, vstate, boot, rcv, orph = ops
-                else:
-                    rem2, assign, mode, vstate, boot, rcv = ops
-                load = mc_vm_stats(assign, rem2, v=v)[0] \
-                    if use_kernel else col_sum(rem2 * (rem2 > 0))
-                out = _migrate_spread(
-                    do_hib, affected, rem2, load, vstate, boot, credits,
-                    assign, mode, rcv, arr, sc, t1,
-                    allow_burstable=policy.use_burstables,
-                    rounds=mig_rounds, track_moved=track_orph,
-                    cascade=dest_cascade, dl2=dl2)
-                if track_orph:
-                    # ledger (§2.10): a group no round re-placed stays
-                    # frozen on its hibernated column — retry below
-                    rem2, assign, mode, vstate, boot, rcv, moved = out
-                    return (rem2, assign, mode, vstate, boot, rcv,
-                            orph | (affected & ~moved))
-                return out
-
-            ops0 = (rem2, assign, mode, vstate, boot, rcv) + \
-                ((orph,) if track_orph else ())
-            out = jax.lax.cond(jnp.any(affected), mig,
-                               lambda ops: ops, ops0)
-            if track_orph:
-                (rem2, assign, mode, vstate, boot, rcv, orph) = out
+        with jax.named_scope("mc.progress_billing"):
+            t = i.astype(jnp.float32) * dt     # [S]; slot covers [t, t + dt)
+            t1 = t + dt
+            # this slot's pregenerated market events (DESIGN.md §2.4)
+            if adaptive:
+                # scenarios sit on different slots: per-row gather
+                # (tensor-relative index — the tensor is anchored at slot0)
+                ir = jnp.minimum(i - slot0, n_slots - 1)
+                hib_k, hib_u = ev.hib_k[rows, ir], ev.hib_u[rows, ir]
+                res_k, res_u = ev.res_k[rows, ir], ev.res_u[rows, ir]
+                if has_term:
+                    term_k, term_u = ev.term_k[rows, ir], ev.term_u[rows, ir]
             else:
-                (rem2, assign, mode, vstate, boot, rcv) = out
-        # else: freeze in place (HADS) — tasks stay attached, no progress
-        # while the column is hibernated, exact progress preserved.
+                # lockstep slot walk: one dynamic slice, as before
+                i0 = i[0] - slot0
+                hib_k = jax.lax.dynamic_index_in_dim(ev.hib_k, i0, 1,
+                                                     keepdims=False)
+                hib_u = jax.lax.dynamic_index_in_dim(ev.hib_u, i0, 1,
+                                                     keepdims=False)
+                res_k = jax.lax.dynamic_index_in_dim(ev.res_k, i0, 1,
+                                                     keepdims=False)
+                res_u = jax.lax.dynamic_index_in_dim(ev.res_u, i0, 1,
+                                                     keepdims=False)
+                if has_term:
+                    term_k = jax.lax.dynamic_index_in_dim(ev.term_k, i0, 1,
+                                                          keepdims=False)
+                    term_u = jax.lax.dynamic_index_in_dim(ev.term_u, i0, 1,
+                                                          keepdims=False)
 
-        # ---- resume events (beneficiaries among hibernated columns) -----
-        res = _select(res_u, vstate == VM_HIBERNATED, res_k) & gate[:, None]
-        nres = nres + jnp.sum(res, axis=1)
-        vstate = jnp.where(res, VM_ACTIVE, vstate)
+            # ---- progress over [t, t + dt) ----------------------------------
+            active = vstate == VM_ACTIVE
+            live = jnp.clip((t1[:, None] - boot) / dt, 0.0, 1.0) * active \
+                * in_h[:, None]
+            rate_t = jnp.take_along_axis(live, assign, axis=1)
+            cred_ok = jnp.take_along_axis(credits > 1e-9, assign, axis=1)
+            sfac = jnp.where((mode == 1) | (burst_t & ~cred_ok),
+                             gv(bfrac, assign), 1.0)
+            run = run0
+            drem = dt * rate_t * gv(speed, assign) * sfac * run
+            rem2 = jnp.maximum(rem - drem, 0.0)
+            newly = pending & (rem2 <= 0.0)
+            frac = jnp.clip(rem / jnp.maximum(drem, 1e-9), 0.0, 1.0)
+            done_at = jnp.where(newly, t[:, None] + dt * frac, done_at)
 
-        if policy.deferred_migration:
-            # deferred-HADS migration at the latest safe instant
-            # (conservative single-wave estimate on the slowest on-demand
-            # type, mirroring Simulator._hads_latest_safe_time); under
-            # hibernation="freeze" tasks stay frozen until resume instead
-            t_safe = dl2 - (sc["omega"] + maxw / sc["od_speed"]
-                            + sc["restore"] + sc["margin"])
-            fire = (vstate == VM_HIBERNATED) & (cnt > 0.5) & \
-                (t1[:, None] >= t_safe - dt) & gate[:, None]
-            aff2 = (rem2 > 0) & jnp.take_along_axis(fire, assign, axis=1)
-            do2 = jnp.any(aff2, axis=1)
+            # ---- billing (pauses during hibernation, ends at termination /
+            # scenario completion) + burstable credit accrual -----------------
+            billed = billed + dt * live * gate[:, None]
+            spend = jnp.einsum("sbv,sb->sv", ohp,
+                               (run & (mode == 0)).astype(jnp.float32))
+            credits = jnp.where(
+                active & burst_v,
+                jnp.clip(credits + dt * live * crate_v
+                         - (dt / sc["bperiod"]) * spend, 0.0, cap),
+                credits)
 
-            def defer(ops):
-                if track_orph:
+            rcv = jnp.zeros((s, v), bool)      # columns given tasks this slot
+
+        with jax.named_scope("mc.terminate"):
+            # victims for both loss events: active, booted, spot columns
+            hib_elig = active & bc(spot) & (boot <= t1[:, None])
+
+            # ---- terminate events (§2.8): the provider reclaims the
+            # column — state lost.  Resolved BEFORE hibernation (terminate
+            # wins slot collisions) over the same eligible set; a terminated
+            # column is then excluded from this slot's hibernation picks.
+            # Billing stops structurally: live requires VM_ACTIVE, and
+            # resume eligibility is VM_HIBERNATED, so a terminated column
+            # never bills or revives.
+            # Unfinished tasks roll back to the checkpoint floor and ALWAYS
+            # re-enter Alg. 4 migration — with memory lost there is no state
+            # to freeze in place, whatever the hibernation axis says.
+            if has_term:
+                trm = _select(term_u, hib_elig, term_k) & gate[:, None]
+                do_trm = jnp.any(trm, axis=1)
+                nterm = nterm + jnp.sum(trm, axis=1)
+                vstate = jnp.where(trm, VM_TERMINATED, vstate)
+                hib_elig = hib_elig & ~trm
+                aff_t = jnp.take_along_axis(trm, assign, axis=1) & (rem2 > 0)
+
+                def migt(ops):
                     rem2, assign, mode, vstate, boot, rcv, orph = ops
-                else:
-                    rem2, assign, mode, vstate, boot, rcv = ops
-                load = mc_vm_stats(assign, rem2, v=v)[0] \
-                    if use_kernel else col_sum(rem2 * (rem2 > 0))
-                out = _migrate_spread(
-                    do2, aff2, rem2, load, vstate, boot, credits, assign,
-                    mode, rcv, arr, sc, t1, allow_burstable=False,
-                    rounds=mig_rounds, track_moved=track_orph,
-                    cascade=dest_cascade, dl2=dl2)
+                    load = mc_vm_stats(assign, rem2, v=v)[0] \
+                        if use_kernel else col_sum(rem2 * (rem2 > 0))
+                    (rem2, assign, mode, vstate, boot, rcv,
+                     moved) = _migrate_spread(
+                        do_trm, aff_t, rem2, load, vstate, boot, credits,
+                        assign, mode, rcv, arr, sc, t1,
+                        allow_burstable=policy.use_burstables,
+                        rounds=mig_rounds, track_moved=True,
+                        cascade=dest_cascade, dl2=dl2)
+                    # ledger (§2.10): an affected task no round re-placed is
+                    # stranded on its (now dead) column — record it for the
+                    # retry pass below / service re-admission
+                    orph = orph | (aff_t & ~moved)
+                    return rem2, assign, mode, vstate, boot, rcv, orph
+
+                ctr = entered(ctr, "terminate", jnp.any(aff_t))
+                (rem2, assign, mode, vstate, boot, rcv, orph) = jax.lax.cond(
+                    jnp.any(aff_t), migt, lambda ops: ops,
+                    (rem2, assign, mode, vstate, boot, rcv, orph))
+
+        with jax.named_scope("mc.hibernate_alg4"):
+            # ---- hibernation events (victims: requested count resolved
+            # against the live eligible set — active, booted, spot) -------
+            hib = _select(hib_u, hib_elig, hib_k) & \
+                gate[:, None]
+            do_hib = jnp.any(hib, axis=1)
+            nhib = nhib + jnp.sum(hib, axis=1)
+            vstate = jnp.where(hib, VM_HIBERNATED, vstate)
+
+            if policy.immediate_migration:
+                # Alg. 4: checkpoint rollback + spread argmin re-assignment
+                affected = jnp.take_along_axis(hib, assign, axis=1) & \
+                    (rem2 > 0)
+
+                def mig(ops):
+                    if track_orph:
+                        rem2, assign, mode, vstate, boot, rcv, orph = ops
+                    else:
+                        rem2, assign, mode, vstate, boot, rcv = ops
+                    load = mc_vm_stats(assign, rem2, v=v)[0] \
+                        if use_kernel else col_sum(rem2 * (rem2 > 0))
+                    out = _migrate_spread(
+                        do_hib, affected, rem2, load, vstate, boot, credits,
+                        assign, mode, rcv, arr, sc, t1,
+                        allow_burstable=policy.use_burstables,
+                        rounds=mig_rounds, track_moved=track_orph,
+                        cascade=dest_cascade, dl2=dl2)
+                    if track_orph:
+                        # ledger (§2.10): a group no round re-placed stays
+                        # frozen on its hibernated column — retry below
+                        rem2, assign, mode, vstate, boot, rcv, moved = out
+                        return (rem2, assign, mode, vstate, boot, rcv,
+                                orph | (affected & ~moved))
+                    return out
+
+                ops0 = (rem2, assign, mode, vstate, boot, rcv) + \
+                    ((orph,) if track_orph else ())
+                ctr = entered(ctr, "hibernate", jnp.any(affected))
+                out = jax.lax.cond(jnp.any(affected), mig,
+                                   lambda ops: ops, ops0)
                 if track_orph:
-                    # ledger (§2.10): a fired-but-infeasible deferred bag
-                    # is past its safe instant — every later boundary's
-                    # retry is its only remaining route to completion
-                    rem2, assign, mode, vstate, boot, rcv, moved = out
-                    return (rem2, assign, mode, vstate, boot, rcv,
-                            orph | (aff2 & ~moved))
-                return out
+                    (rem2, assign, mode, vstate, boot, rcv, orph) = out
+                else:
+                    (rem2, assign, mode, vstate, boot, rcv) = out
+            # else: freeze in place (HADS) — tasks stay attached, no progress
+            # while the column is hibernated, exact progress preserved.
 
-            ops0 = (rem2, assign, mode, vstate, boot, rcv) + \
-                ((orph,) if track_orph else ())
-            out = jax.lax.cond(jnp.any(aff2), defer, lambda ops: ops, ops0)
-            if track_orph:
-                (rem2, assign, mode, vstate, boot, rcv, orph) = out
+        with jax.named_scope("mc.resume_defer"):
+            # ---- resume events (beneficiaries among hibernated columns) -----
+            res = _select(res_u, vstate == VM_HIBERNATED, res_k) & \
+                gate[:, None]
+            nres = nres + jnp.sum(res, axis=1)
+            vstate = jnp.where(res, VM_ACTIVE, vstate)
+
+            if policy.deferred_migration:
+                # deferred-HADS migration at the latest safe instant
+                # (conservative single-wave estimate on the slowest on-demand
+                # type, mirroring Simulator._hads_latest_safe_time); under
+                # hibernation="freeze" tasks stay frozen until resume instead
+                t_safe = dl2 - (sc["omega"] + maxw / sc["od_speed"]
+                                + sc["restore"] + sc["margin"])
+                fire = (vstate == VM_HIBERNATED) & (cnt > 0.5) & \
+                    (t1[:, None] >= t_safe - dt) & gate[:, None]
+                aff2 = (rem2 > 0) & jnp.take_along_axis(fire, assign, axis=1)
+                do2 = jnp.any(aff2, axis=1)
+
+                def defer(ops):
+                    if track_orph:
+                        rem2, assign, mode, vstate, boot, rcv, orph = ops
+                    else:
+                        rem2, assign, mode, vstate, boot, rcv = ops
+                    load = mc_vm_stats(assign, rem2, v=v)[0] \
+                        if use_kernel else col_sum(rem2 * (rem2 > 0))
+                    out = _migrate_spread(
+                        do2, aff2, rem2, load, vstate, boot, credits, assign,
+                        mode, rcv, arr, sc, t1, allow_burstable=False,
+                        rounds=mig_rounds, track_moved=track_orph,
+                        cascade=dest_cascade, dl2=dl2)
+                    if track_orph:
+                        # ledger (§2.10): a fired-but-infeasible deferred bag
+                        # is past its safe instant — every later boundary's
+                        # retry is its only remaining route to completion
+                        rem2, assign, mode, vstate, boot, rcv, moved = out
+                        return (rem2, assign, mode, vstate, boot, rcv,
+                                orph | (aff2 & ~moved))
+                    return out
+
+                ops0 = (rem2, assign, mode, vstate, boot, rcv) + \
+                    ((orph,) if track_orph else ())
+                ctr = entered(ctr, "defer", jnp.any(aff2))
+                out = jax.lax.cond(jnp.any(aff2), defer, lambda ops: ops,
+                                   ops0)
+                if track_orph:
+                    (rem2, assign, mode, vstate, boot, rcv, orph) = out
+                else:
+                    (rem2, assign, mode, vstate, boot, rcv) = out
+
+        with jax.named_scope("mc.ac_block"):
+            # ---- Allocation-Cycle boundary: work stealing + idle termination
+            # is_ac is per-scenario [S] — scenarios on different clocks reach
+            # their AC edges in different loop iterations
+            i1 = i + 1
+            if ac_aligned:
+                is_ac = (i1 > sc["boot_slots"]) & \
+                    ((i1 - sc["boot_slots"]) % sc["ac_slots"] == 0)
             else:
-                (rem2, assign, mode, vstate, boot, rcv) = out
+                # dt need not divide ω/AC under adaptive stepping: the slot
+                # whose (t, t1] interval contains an AC edge handles it
+                f1 = jnp.floor((t1 - sc["omega"]) / sc["ac_seconds"])
+                f0 = jnp.floor((t - sc["omega"]) / sc["ac_seconds"])
+                is_ac = (t1 >= sc["omega"] + sc["ac_seconds"]) & (f1 > f0)
+            booted = boot <= t1[:, None]
 
-        # ---- Allocation-Cycle boundary: work stealing + idle termination
-        # is_ac is per-scenario [S] — scenarios on different clocks reach
-        # their AC edges in different loop iterations
-        i1 = i + 1
-        if ac_aligned:
-            is_ac = (i1 > sc["boot_slots"]) & \
-                ((i1 - sc["boot_slots"]) % sc["ac_slots"] == 0)
-        else:
-            # dt need not divide ω/AC under adaptive stepping: the slot
-            # whose (t, t1] interval contains an AC edge handles it
-            f1 = jnp.floor((t1 - sc["omega"]) / sc["ac_seconds"])
-            f0 = jnp.floor((t - sc["omega"]) / sc["ac_seconds"])
-            is_ac = (t1 >= sc["omega"] + sc["ac_seconds"]) & (f1 > f0)
-        booted = boot <= t1[:, None]
+            def ac_block(ops):
+                vstate, assign, mode = ops
+                cnt_live = cnt - col_sum(newly.astype(jnp.float32))
+                if policy.work_stealing:
+                    a, m, cl = assign, mode, cnt_live
+                    for _ in range(steal_rounds):
+                        idle = (vstate == VM_ACTIVE) & booted & (cl < 0.5) & \
+                            (is_ac & gate)[:, None]
+                        thief = jnp.argmin(jnp.where(idle, iota_v, v + 1),
+                                           axis=1).astype(jnp.int32)
+                        has_thief = jnp.any(idle, axis=1)
+                        queued = jnp.where(bc(burst), 0.0,
+                                           jnp.maximum(cl - bc(cores), 0.0))
+                        vict = jnp.argmax(queued, axis=1).astype(jnp.int32)
+                        has_q = jnp.max(queued, axis=1) > 0.5
+                        on_vict = (rem2 > 0) & (a == vict[:, None]) & \
+                            (rank >= g1(cores, vict)[:, None])
+                        tsk = jnp.argmax(jnp.where(on_vict, rem2, -1.0),
+                                         axis=1).astype(jnp.int32)
+                        do_steal = has_thief & has_q & gate & \
+                            jnp.any(on_vict, axis=1) & \
+                            (g1(mem_t, tsk) <= g1(memv, thief) + 1e-6)
+                        a = a.at[rows, tsk].set(
+                            jnp.where(do_steal, thief, a[rows, tsk]))
+                        m = m.at[rows, tsk].set(
+                            jnp.where(do_steal,
+                                      g1(burst, thief).astype(jnp.int32),
+                                      m[rows, tsk]))
+                        shift = do_steal[:, None].astype(jnp.float32)
+                        cl = cl + shift * (iota_v == thief[:, None]) \
+                            - shift * (iota_v == vict[:, None])
+                    assign, mode, cnt_live = a, m, cl
+                term = (vstate == VM_ACTIVE) & booted & (cnt_live < 0.5) & \
+                    ~bc(burst) & ~rcv & (is_ac & gate)[:, None]
+                vstate = jnp.where(term, VM_TERMINATED, vstate)
+                return vstate, assign, mode
 
-        def ac_block(ops):
-            vstate, assign, mode = ops
-            cnt_live = cnt - col_sum(newly.astype(jnp.float32))
-            if policy.work_stealing:
-                a, m, cl = assign, mode, cnt_live
-                for _ in range(steal_rounds):
-                    idle = (vstate == VM_ACTIVE) & booted & (cl < 0.5) & \
-                        (is_ac & gate)[:, None]
-                    thief = jnp.argmin(jnp.where(idle, iota_v, v + 1),
-                                       axis=1).astype(jnp.int32)
-                    has_thief = jnp.any(idle, axis=1)
-                    queued = jnp.where(bc(burst), 0.0,
-                                       jnp.maximum(cl - bc(cores), 0.0))
-                    vict = jnp.argmax(queued, axis=1).astype(jnp.int32)
-                    has_q = jnp.max(queued, axis=1) > 0.5
-                    on_vict = (rem2 > 0) & (a == vict[:, None]) & \
-                        (rank >= g1(cores, vict)[:, None])
-                    tsk = jnp.argmax(jnp.where(on_vict, rem2, -1.0),
-                                     axis=1).astype(jnp.int32)
-                    do_steal = has_thief & has_q & gate & \
-                        jnp.any(on_vict, axis=1) & \
-                        (g1(mem_t, tsk) <= g1(memv, thief) + 1e-6)
-                    a = a.at[rows, tsk].set(
-                        jnp.where(do_steal, thief, a[rows, tsk]))
-                    m = m.at[rows, tsk].set(
-                        jnp.where(do_steal,
-                                  g1(burst, thief).astype(jnp.int32),
-                                  m[rows, tsk]))
-                    shift = do_steal[:, None].astype(jnp.float32)
-                    cl = cl + shift * (iota_v == thief[:, None]) \
-                        - shift * (iota_v == vict[:, None])
-                assign, mode, cnt_live = a, m, cl
-            term = (vstate == VM_ACTIVE) & booted & (cnt_live < 0.5) & \
-                ~bc(burst) & ~rcv & (is_ac & gate)[:, None]
-            vstate = jnp.where(term, VM_TERMINATED, vstate)
-            return vstate, assign, mode
+            ctr = entered(ctr, "ac", jnp.any(is_ac))
+            (vstate, assign, mode) = jax.lax.cond(
+                jnp.any(is_ac), ac_block, lambda ops: ops,
+                (vstate, assign, mode))
 
-        (vstate, assign, mode) = jax.lax.cond(
-            jnp.any(is_ac), ac_block, lambda ops: ops,
-            (vstate, assign, mode))
+        with jax.named_scope("mc.retry"):
+            if track_orph:
+                # ---- fault-recovery retry (§2.10): re-attempt Alg. 4 for
+                # orphans still stranded on a non-running column.  Runs at
+                # every full step — under adaptive stepping those ARE the
+                # event/AC boundaries, matching the DES retry hooks (boot
+                # done / resume / AC check), and a step where capacity has
+                # not returned is an infeasible no-op (nothing mutates, the
+                # round bound is not consumed).  Ordered after the AC block
+                # so destinations reflect this slot's resumes, launches and
+                # idle terminations — the same world the DES retries see.
+                # stuck = parked on any non-running column: terminated (both
+                # ledger sites) or still hibernated (a deferred bag past its
+                # safe instant).  An orphan whose column resumed or that a
+                # steal re-placed is live again — excluded here, and its
+                # stale ledger bit is dropped so a later hibernation of its new
+                # column doesn't resurrect it.
+                stuck = jnp.take_along_axis(vstate != VM_ACTIVE, assign,
+                                            axis=1)
+                orph = orph & stuck
+                want = orph & (rem2 > 0.0) & gate[:, None]
+                can = jnp.any(want, axis=1) & (oret < orphan_retry)
 
-        if track_orph:
-            # ---- fault-recovery retry (§2.10): re-attempt Alg. 4 for
-            # orphans still stranded on a non-running column.  Runs at
-            # every full step — under adaptive stepping those ARE the
-            # event/AC boundaries, matching the DES retry hooks (boot
-            # done / resume / AC check), and a step where capacity has
-            # not returned is an infeasible no-op (nothing mutates, the
-            # round bound is not consumed).  Ordered after the AC block
-            # so destinations reflect this slot's resumes, launches and
-            # idle terminations — the same world the DES retries see.
-            # stuck = parked on any non-running column: terminated (both
-            # ledger sites) or still hibernated (a deferred bag past its
-            # safe instant).  An orphan whose column resumed or that a
-            # steal re-placed is live again — excluded here, and its stale
-            # ledger bit is dropped so a later hibernation of its new
-            # column doesn't resurrect it.
-            stuck = jnp.take_along_axis(vstate != VM_ACTIVE, assign,
-                                        axis=1)
-            orph = orph & stuck
-            want = orph & (rem2 > 0.0) & gate[:, None]
-            can = jnp.any(want, axis=1) & (oret < orphan_retry)
+                def retry(ops):
+                    rem2, assign, mode, vstate, boot, rcv, orph, oret = ops
+                    load = mc_vm_stats(assign, rem2, v=v)[0] \
+                        if use_kernel else col_sum(rem2 * (rem2 > 0))
+                    (rem2, assign, mode, vstate, boot, rcv,
+                     moved) = _migrate_spread(
+                        can, want, rem2, load, vstate, boot, credits,
+                        assign, mode, rcv, arr, sc, t1,
+                        allow_burstable=policy.use_burstables,
+                        rounds=mig_rounds, track_moved=True,
+                        cascade=dest_cascade, dl2=dl2)
+                    orph = orph & ~moved
+                    oret = oret + jnp.any(moved, axis=1).astype(jnp.int32)
+                    return rem2, assign, mode, vstate, boot, rcv, orph, oret
 
-            def retry(ops):
-                rem2, assign, mode, vstate, boot, rcv, orph, oret = ops
-                load = mc_vm_stats(assign, rem2, v=v)[0] \
-                    if use_kernel else col_sum(rem2 * (rem2 > 0))
-                (rem2, assign, mode, vstate, boot, rcv,
-                 moved) = _migrate_spread(
-                    can, want, rem2, load, vstate, boot, credits,
-                    assign, mode, rcv, arr, sc, t1,
-                    allow_burstable=policy.use_burstables,
-                    rounds=mig_rounds, track_moved=True,
-                    cascade=dest_cascade, dl2=dl2)
-                orph = orph & ~moved
-                oret = oret + jnp.any(moved, axis=1).astype(jnp.int32)
-                return rem2, assign, mode, vstate, boot, rcv, orph, oret
-
-            (rem2, assign, mode, vstate, boot, rcv, orph,
-             oret) = jax.lax.cond(
-                jnp.any(want & can[:, None]), retry, lambda ops: ops,
-                (rem2, assign, mode, vstate, boot, rcv, orph, oret))
+                ctr = entered(ctr, "retry", jnp.any(want & can[:, None]))
+                (rem2, assign, mode, vstate, boot, rcv, orph,
+                 oret) = jax.lax.cond(
+                    jnp.any(want & can[:, None]), retry, lambda ops: ops,
+                    (rem2, assign, mode, vstate, boot, rcv, orph, oret))
 
         # exited rows park at their own exit slot — under the
         # row-parametric layout that can sit strictly inside the padded
@@ -1254,7 +1280,7 @@ def _mc_run_impl(arr: dict, sc: dict, ev: EventTensor,
                nsteps + 1, visited.at[rows, i_mark].set(True, mode="drop"))
         if track_orph:
             nxt = nxt + (orph, oret)
-        return nxt
+        return nxt + (ctr,)
 
     out = jax.lax.while_loop(cond, step, carry)
     (i_fin, vstate_f, boot_f, billed, credits_f, rem, assign_f, mode_f,
@@ -1281,7 +1307,8 @@ def _mc_run_impl(arr: dict, sc: dict, ev: EventTensor,
            "n_term": nterm, "n_steps": nsteps, "exit_slots": i_fin,
            "visited": visited,
            "n_done": jnp.sum(done_at < BIG * 0.5, axis=1),
-           "n_orphan": n_orphan, "n_retry": n_retry}
+           "n_orphan": n_orphan, "n_retry": n_retry,
+           "branch_entries": out[-1]}
     if return_state:
         res["state"] = EngineState(
             slot=i_fin, vstate=vstate_f, boot=boot_f, billed=billed,
@@ -1315,7 +1342,8 @@ def _mc_run(arr: dict, sc: dict, ev: EventTensor,
     partitioned by GSPMD and need exactly this per-device program.  Plan
     arrays are replicated, except the megabatch row-parametric layout's
     [S, ·] rows (and the [S] per-row scalars), which split with the
-    scenarios.  ``n_steps`` is the maximum over the devices' loops."""
+    scenarios.  ``n_steps`` is the maximum over the devices' loops,
+    ``branch_entries`` the sum."""
     kw = dict(policy=policy, steal_rounds=steal_rounds,
               mig_rounds=mig_rounds, mem_safe=mem_safe,
               use_kernel=use_kernel, stepping=stepping,
@@ -1325,18 +1353,20 @@ def _mc_run(arr: dict, sc: dict, ev: EventTensor,
         return _mc_run_impl(arr, sc, ev, state, s=s, **kw)
     rows, rep = PartitionSpec(mesh.axis_names[0]), PartitionSpec()
     rowp = arr["speed"].ndim == 2
-    arr_spec = {k: rows if rowp and k != "burst_idx" else rep for k in arr}
+    arr_spec = {k: rows if rowp else rep for k in arr}
     sc_spec = {k: rows if jnp.ndim(x) == 1 else rep for k, x in sc.items()}
 
     def local(arr, sc, ev, state):
         out = _mc_run_impl(arr, sc, ev, state, s=s // mesh.size, **kw)
         out["n_steps"] = out["n_steps"][None]
+        out["branch_entries"] = out["branch_entries"][None]
         return out
 
     out = jax.shard_map(local, mesh=mesh,
                         in_specs=(arr_spec, sc_spec, rows, rows),
                         out_specs=rows, check_vma=False)(arr, sc, ev, state)
     out["n_steps"] = jnp.max(out["n_steps"])
+    out["branch_entries"] = jnp.sum(out["branch_entries"], axis=0)
     return out
 
 
@@ -1478,74 +1508,79 @@ def run_mc_events(job: Job, plan: PrimaryPlan, cfg: CloudConfig,
     else:
         arr, uids, mem_safe = _plan_arrays_cached(job, plan, cfg,
                                                   params.ovh)
-    ev.validate()                   # diagnose malformed tensors first —
-    if params.stepping == "adaptive":   # with_index would crash rawly
-        ev = ev.with_index()
-    if ev.n_vms != len(uids):
-        raise ValueError(
-            f"event tensor has V={ev.n_vms} columns, plan has "
-            f"{len(uids)} launchable instances — regenerate the tensor "
-            f"for this plan (see plan_column_uids)")
-    slot0 = _slot_of(t0_s, params.dt, "t0_s")
-    n_abs = slot0 + ev.n_slots      # absolute horizon in slots
-    sc = _scalars(job, cfg, params, n_abs)
-    sc["slot0"] = jnp.int32(slot0)
-    if stop_s is not None:
-        stop_slots = _slot_of(stop_s, params.dt, "stop_s")
-        if not slot0 < stop_slots <= n_abs:
+    with span("mc.run", S=ev.n_scenarios, B=arr["total"].shape[-1],
+              V=ev.n_vms, policy=plan.policy.name):
+        ev.validate()                   # diagnose malformed tensors first —
+        if params.stepping == "adaptive":   # with_index would crash rawly
+            ev = ev.with_index()
+        if ev.n_vms != len(uids):
             raise ValueError(
-                f"stop_s={stop_s} must land strictly after t0_s={t0_s} "
-                f"and inside the tensor horizon ({n_abs} slots)")
-        sc["stop_slots"] = jnp.int32(stop_slots)
-    if state is not None:
-        b = arr["total"].shape[-1]
-        if (state.n_scenarios, state.n_vms, state.n_tasks) != \
-                (ev.n_scenarios, ev.n_vms, b):
-            raise ValueError(
-                f"state shape (S={state.n_scenarios}, V={state.n_vms}, "
-                f"B={state.n_tasks}) does not match the run "
-                f"(S={ev.n_scenarios}, V={ev.n_vms}, B={b})")
-        if params.stepping == "slot":
-            slots = np.unique(np.asarray(jax.device_get(state.slot)))
-            if len(slots) > 1:
+                f"event tensor has V={ev.n_vms} columns, plan has "
+                f"{len(uids)} launchable instances — regenerate the tensor "
+                f"for this plan (see plan_column_uids)")
+        slot0 = _slot_of(t0_s, params.dt, "t0_s")
+        n_abs = slot0 + ev.n_slots      # absolute horizon in slots
+        sc = _scalars(job, cfg, params, n_abs)
+        sc["slot0"] = jnp.int32(slot0)
+        if stop_s is not None:
+            stop_slots = _slot_of(stop_s, params.dt, "stop_s")
+            if not slot0 < stop_slots <= n_abs:
                 raise ValueError(
-                    "stepping='slot' walks all scenarios in lockstep — "
-                    f"re-entry needs a uniform slot clock, got {slots}")
-    if os.environ.get("REPRO_SCHEMA_CHECKS"):
-        # aval-level boundary contract (DESIGN.md §2.11) — shape/dtype/
-        # weak-type schemas beyond validate()'s shape checks; no compute.
-        from repro.analysis.schema import (check_engine_state,
-                                           check_event_tensor)
-        dims = check_event_tensor(ev)
+                    f"stop_s={stop_s} must land strictly after t0_s={t0_s} "
+                    f"and inside the tensor horizon ({n_abs} slots)")
+            sc["stop_slots"] = jnp.int32(stop_slots)
         if state is not None:
-            check_engine_state(
-                state, bind={"S": dims["S"], "V": dims["V"]})
-    want_state = bool(stop_s is not None) if return_state is None \
-        else return_state
-    on_cpu = jax.default_backend() == "cpu"
-    out = _mc_jit(donate and not on_cpu)(
-        arr, sc, ev, state, s=ev.n_scenarios, mesh=scenario_mesh(ev),
-        **engine_args(plan.policy.engine_view(), params, cfg, mem_safe),
-        return_state=want_state)
-    out = jax.device_get(out)
-    unfinished = out["unfinished"].astype(int)
-    makespan = out["makespan"]
-    met = (unfinished == 0) & (makespan <= job.deadline_s + params.dt + 1e-6)
-    return MCResult(
-        policy=plan.policy.name, scenario=label, dt=params.dt,
-        deadline_s=job.deadline_s,
-        cost=out["cost"], makespan=makespan, deadline_met=met,
-        unfinished=unfinished,
-        n_hibernations=out["n_hib"].astype(int),
-        n_resumes=out["n_res"].astype(int),
-        billed_s=out["billed"], vm_uids=list(uids),
-        stepping=params.stepping, n_steps=int(out["n_steps"]),
-        exit_slots=out["exit_slots"].astype(int), visited=out["visited"],
-        n_terminations=out["n_term"].astype(int),
-        state=out.get("state"),
-        n_orphans=out["n_orphan"].astype(int),
-        retry_rounds=out["n_retry"].astype(int),
-        n_done=out["n_done"].astype(int))
+            b = arr["total"].shape[-1]
+            if (state.n_scenarios, state.n_vms, state.n_tasks) != \
+                    (ev.n_scenarios, ev.n_vms, b):
+                raise ValueError(
+                    f"state shape (S={state.n_scenarios}, V={state.n_vms}, "
+                    f"B={state.n_tasks}) does not match the run "
+                    f"(S={ev.n_scenarios}, V={ev.n_vms}, B={b})")
+            if params.stepping == "slot":
+                slots = np.unique(np.asarray(jax.device_get(state.slot)))
+                if len(slots) > 1:
+                    raise ValueError(
+                        "stepping='slot' walks all scenarios in lockstep — "
+                        f"re-entry needs a uniform slot clock, got {slots}")
+        if os.environ.get("REPRO_SCHEMA_CHECKS"):
+            # aval-level boundary contract (DESIGN.md §2.11) — shape/dtype/
+            # weak-type schemas beyond validate()'s shape checks; no compute.
+            from repro.analysis.schema import (check_engine_state,
+                                               check_event_tensor)
+            dims = check_event_tensor(ev)
+            if state is not None:
+                check_engine_state(
+                    state, bind={"S": dims["S"], "V": dims["V"]})
+        want_state = bool(stop_s is not None) if return_state is None \
+            else return_state
+        on_cpu = jax.default_backend() == "cpu"
+        out = _mc_jit(donate and not on_cpu)(
+            arr, sc, ev, state, s=ev.n_scenarios, mesh=scenario_mesh(ev),
+            **engine_args(plan.policy.engine_view(), params, cfg, mem_safe),
+            return_state=want_state)
+        out = jax.device_get(out)
+        unfinished = out["unfinished"].astype(int)
+        makespan = out["makespan"]
+        met = (unfinished == 0) & \
+            (makespan <= job.deadline_s + params.dt + 1e-6)
+        return MCResult(
+            policy=plan.policy.name, scenario=label, dt=params.dt,
+            deadline_s=job.deadline_s,
+            cost=out["cost"], makespan=makespan, deadline_met=met,
+            unfinished=unfinished,
+            n_hibernations=out["n_hib"].astype(int),
+            n_resumes=out["n_res"].astype(int),
+            billed_s=out["billed"], vm_uids=list(uids),
+            stepping=params.stepping, n_steps=int(out["n_steps"]),
+            exit_slots=out["exit_slots"].astype(int), visited=out["visited"],
+            n_terminations=out["n_term"].astype(int),
+            state=out.get("state"),
+            n_orphans=out["n_orphan"].astype(int),
+            retry_rounds=out["n_retry"].astype(int),
+            n_done=out["n_done"].astype(int),
+            branch_entries=dict(zip(
+                BRANCHES, np.asarray(out["branch_entries"]).tolist())))
 
 
 def run_mc(job: Job, plan: PrimaryPlan, cfg: CloudConfig,

@@ -278,13 +278,6 @@ def _fused_inputs(cells: list[_Cell], evs: list[EventTensor],
 
     arr = {k: jnp.asarray(np.concatenate([b[k] for b in blocks]))
            for k in blocks[0]}
-    # per-row burstable sets are ragged, so the fused call's static
-    # credit subset is the *union* of the plans' burstable positions —
-    # columns outside a row's own set have crate = ccap = 0 there and
-    # stay credit-inert, while the per-iteration credit work stays
-    # O(union), not O(v_pad)
-    arr["burst_idx"] = jnp.asarray(
-        np.where(np.asarray(arr["burst"]).any(axis=0))[0], jnp.int32)
 
     sc = _scalars(cells[0].job, cfg, params, slots_pad)
     sc["deadline"] = jnp.asarray(np.concatenate(
