@@ -4,9 +4,11 @@ Two evaluators exist by design (DESIGN.md §2.1):
 
 * here: ``evaluate`` — the exact packer.  Deterministic LPT order per VM,
   per-core free lists, and a timeline memory check equivalent to the paper's
-  Eq. 2/3 constraints.  Used by the greedy constructor's ``check_schedule``,
-  by the simulator to materialise the primary map, and to re-validate every
-  incumbent the ILS accepts.
+  Eq. 2/3 constraints.  Used by ``check_schedule`` (which the greedy
+  constructor calls only where a VM's summed task memory could exceed its
+  capacity; it answers the other checks from its kept LPT packing, see
+  ``core/greedy``), by the simulator to materialise the primary map, and to
+  re-validate every incumbent the ILS accepts.
 * ``repro.core.ils_jax.fitness_fast`` — the vectorised bound used inside the
   batched search (backed by the ``sched_fitness`` Pallas kernel).
 """

@@ -13,9 +13,12 @@ from __future__ import annotations
 import dataclasses
 import enum
 import math
-from typing import Sequence
+from typing import TYPE_CHECKING, Sequence
 
 import numpy as np
+
+if TYPE_CHECKING:
+    from .greedy import GreedyCounters
 
 
 class Market(enum.Enum):
@@ -222,6 +225,9 @@ class Solution:
     modes: np.ndarray                     # int8[|B|]  -> 0 FULL / 1 BASELINE
     pool: list[VMInstance]
     selected_uids: set[int] = dataclasses.field(default_factory=set)
+    #: what the greedy constructor did to build this solution; None on any
+    #: other solution, copies included
+    greedy_counters: GreedyCounters | None = None
 
     def copy(self) -> "Solution":
         return Solution(self.alloc.copy(), self.modes.copy(), self.pool,
