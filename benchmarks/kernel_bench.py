@@ -2,7 +2,7 @@
 
 The container is CPU-only: wall-times here are for the *reference* paths
 (the Pallas bodies run in interpret mode for validation, not speed); the
-TPU roofline for the kernels comes from the dry-run analysis.
+kernels' TPU rooflines come from the benchmark's traced runs (``bench/``).
 """
 from __future__ import annotations
 
@@ -26,37 +26,6 @@ def _time(fn, *args, reps=3):
 def run() -> list[dict]:
     rows = []
     rng = np.random.default_rng(0)
-
-    # flash attention ref vs blocked-jnp path
-    from repro.kernels.flash_attention.ref import attention_ref
-    from repro.models.layers import _blocked_attention
-    b, s, h, hd = 2, 1024, 4, 64
-    q = jnp.asarray(rng.normal(0, 1, (b, s, h, hd)), jnp.float32)
-    k = jnp.asarray(rng.normal(0, 1, (b, s, h, hd)), jnp.float32)
-    v = jnp.asarray(rng.normal(0, 1, (b, s, h, hd)), jnp.float32)
-    dense = jax.jit(lambda q, k, v: attention_ref(
-        q.transpose(0, 2, 1, 3).reshape(b * h, s, hd),
-        k.transpose(0, 2, 1, 3).reshape(b * h, s, hd),
-        v.transpose(0, 2, 1, 3).reshape(b * h, s, hd)))
-    blocked = jax.jit(lambda q, k, v: _blocked_attention(q, k, v, 0))
-    rows.append({"table": "kernels", "kernel": "attention",
-                 "shape": f"b{b} s{s} h{h} hd{hd}",
-                 "dense_us": round(_time(dense, q, k, v)),
-                 "blocked_us": round(_time(blocked, q, k, v))})
-
-    # rwkv6 scan vs chunked ref math
-    from repro.models.rwkv6 import wkv_scan
-    t = 256
-    r = jnp.asarray(rng.normal(0, 1, (b, t, h, hd)), jnp.float32)
-    kk = jnp.asarray(rng.normal(0, 1, (b, t, h, hd)), jnp.float32)
-    vv = jnp.asarray(rng.normal(0, 1, (b, t, h, hd)), jnp.float32)
-    w = jnp.asarray(rng.uniform(0.9, 0.999, (b, t, h, hd)), jnp.float32)
-    u = jnp.asarray(rng.normal(0, 0.3, (h, hd)), jnp.float32)
-    st = jnp.zeros((b, h, hd, hd), jnp.float32)
-    seq_fn = jax.jit(lambda *a: wkv_scan(*a))
-    rows.append({"table": "kernels", "kernel": "rwkv6_wkv",
-                 "shape": f"b{b} t{t} h{h} hd{hd}",
-                 "scan_us": round(_time(seq_fn, r, kk, vv, w, u, st))})
 
     # sched_fitness ref throughput (the ILS inner loop)
     from repro.kernels.sched_fitness.ref import population_fitness_ref

@@ -143,23 +143,6 @@ def main() -> None:
     print("# Kernel microbenches (CPU reference paths)")
     emit("kernels", kernel_bench.run(), fh)
 
-    # Roofline summary (if dry-run artifacts exist)
-    try:
-        from repro.launch.roofline import load_all
-        rows = load_all("results/dryrun")
-        if rows:
-            print("# Roofline (baseline dry-run artifacts)")
-            emit("roofline",
-                 [{"table": "roofline", "arch": r["arch"],
-                   "shape": r["shape"], "dominant": r["dominant"],
-                   "roofline_fraction": round(r["roofline_fraction"], 3),
-                   "mfu_bound": round(r["mfu_bound"], 3)}
-                  for r in rows], fh)
-    except BenchError:
-        raise
-    except Exception as e:  # pragma: no cover
-        print(f"# roofline skipped: {e}")
-
     fh.close()
     print(f"# total {time.time() - t0:.0f}s -> {args.csv}")
 

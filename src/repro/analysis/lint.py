@@ -305,7 +305,7 @@ def _check_kernel_refs(repo_src: str) -> Iterable[Violation]:
             ref_tree = ast.parse(fh.read())
         ref_syms = {n.name for n in ref_tree.body
                     if isinstance(n, (ast.FunctionDef, ast.AsyncFunctionDef))}
-        for n in ref_tree.body:  # aliases: flash_attention_ref = attention_ref
+        for n in ref_tree.body:  # aliases: <entry>_ref = <other>_ref
             if isinstance(n, ast.Assign):
                 ref_syms.update(t.id for t in n.targets if isinstance(t, ast.Name))
         with open(ops_path) as fh:

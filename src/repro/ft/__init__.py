@@ -1,2 +1,2 @@
-from .checkpoint import (CHECKPOINT_MODES, CheckpointManager,  # noqa: F401
-                         checkpoint_schedule, ovh_checkpoint_period)
+from .checkpoint import (CHECKPOINT_MODES, checkpoint_schedule,  # noqa: F401
+                         ovh_checkpoint_period)

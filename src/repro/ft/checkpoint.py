@@ -1,21 +1,14 @@
-"""Fault-Tolerance Module: atomic, manifest-versioned pytree checkpoints.
+"""Fault-Tolerance Module: checkpoint schedules for preemptible tasks.
 
-The CRIU process snapshot of the paper maps to the complete JAX training
-state: (params, optimizer moments, step, data cursor, rng).  Checkpoints are
-written to a temp file and atomically renamed; a JSON manifest records the
-latest valid step so a torn write can never be restored.  The cadence
-honours the paper's ``ovh`` budget: checkpoint overhead <= ovh x step time.
+The paper checkpoints a running task (a CRIU snapshot) so that a
+hibernated or migrated task resumes from its last checkpoint instead of
+from scratch.  This module holds the arithmetic the engines share: the
+period that keeps checkpoint overhead within the paper's ``ovh`` budget,
+and the per-task ``(total, cp)`` schedules of the policy lattice's
+checkpoint axis (``periodic``, ``off`` and ``random``; DESIGN.md §2.8).
 """
 from __future__ import annotations
 
-import dataclasses
-import json
-import os
-import tempfile
-import time
-from typing import Any
-
-import jax
 import numpy as np
 
 
@@ -101,73 +94,3 @@ def checkpoint_schedule(base_s, ovh: float, mode: str = "periodic", *,
                          f"(one of {CHECKPOINT_MODES})")
     cp = (total / (n_cp + 1)).astype(np.float32)
     return total, cp
-
-
-@dataclasses.dataclass
-class CheckpointManager:
-    directory: str
-    keep: int = 3
-
-    def __post_init__(self):
-        os.makedirs(self.directory, exist_ok=True)
-        self._manifest = os.path.join(self.directory, "MANIFEST.json")
-
-    # -- manifest ------------------------------------------------------
-    def _read_manifest(self) -> dict:
-        if not os.path.exists(self._manifest):
-            return {"steps": []}
-        with open(self._manifest) as f:
-            return json.load(f)
-
-    def _write_manifest(self, man: dict) -> None:
-        fd, tmp = tempfile.mkstemp(dir=self.directory, suffix=".manifest")
-        with os.fdopen(fd, "w") as f:
-            json.dump(man, f)
-        os.replace(tmp, self._manifest)
-
-    def latest_step(self) -> int | None:
-        steps = self._read_manifest()["steps"]
-        return max(steps) if steps else None
-
-    # -- save / restore --------------------------------------------------
-    def save(self, step: int, state: Any, extra: dict | None = None) -> str:
-        leaves, treedef = jax.tree.flatten(state)
-        arrays = {f"leaf_{i}": np.asarray(x) for i, x in enumerate(leaves)}
-        meta = {"step": step, "n_leaves": len(leaves),
-                "extra": extra or {}, "saved_at": time.time()}
-        path = os.path.join(self.directory, f"ckpt_{step:08d}.npz")
-        # NOTE: np.savez appends ".npz" unless the name already ends with it
-        fd, tmp = tempfile.mkstemp(dir=self.directory, suffix=".tmp.npz")
-        os.close(fd)
-        np.savez(tmp, __meta__=json.dumps(meta), **arrays)
-        os.replace(tmp, path)
-
-        man = self._read_manifest()
-        man["steps"] = sorted(set(man["steps"]) | {step})
-        self._write_manifest(man)
-        self._gc(man)
-        return path
-
-    def restore(self, treedef_like: Any, step: int | None = None
-                ) -> tuple[int, Any, dict]:
-        """-> (step, state, extra).  ``treedef_like``: a pytree with the
-        target structure (contents ignored)."""
-        step = step if step is not None else self.latest_step()
-        if step is None:
-            raise FileNotFoundError("no checkpoint in " + self.directory)
-        path = os.path.join(self.directory, f"ckpt_{step:08d}.npz")
-        with np.load(path, allow_pickle=False) as z:
-            meta = json.loads(str(z["__meta__"]))
-            leaves = [z[f"leaf_{i}"] for i in range(meta["n_leaves"])]
-        treedef = jax.tree.structure(treedef_like)
-        return meta["step"], jax.tree.unflatten(treedef, leaves), meta["extra"]
-
-    def _gc(self, man: dict) -> None:
-        steps = sorted(man["steps"])
-        drop = steps[:-self.keep] if self.keep > 0 else []
-        for s in drop:
-            p = os.path.join(self.directory, f"ckpt_{s:08d}.npz")
-            if os.path.exists(p):
-                os.remove(p)
-        man["steps"] = steps[-self.keep:]
-        self._write_manifest(man)

@@ -95,8 +95,8 @@ class Simulator:
         self._primary_uids = set(plan.solution.selected_uids)
         self._orphans: list[TaskRun] = []   # failed migrations awaiting retry
         self._ac_scheduled: set[tuple[int, int]] = set()
-        #: structured execution records for real-payload replay
-        #: (repro.cluster.runtime.TraceExecutor)
+        #: structured execution records: one dict per dispatch, preempt
+        #: and complete event (the trace goldens read them)
         self.records: list[dict] = []
 
     # ------------------------------------------------------------------

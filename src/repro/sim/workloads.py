@@ -8,7 +8,7 @@ the time range, then jitter, reproducing the published min/avg/max bands.
 ED200 (NAS GRID ED, class B): 200 embarrassingly-distributed tasks,
 153.74–177.77 MB.  The paper does not publish ED task durations; base times
 are calibrated (~420 s on C4.large) so the ILS-on-demand makespan lands near
-Table IV's 1887 s — the constant is flagged here per DESIGN.md §5(6).
+Table IV's 1887 s — the constant is flagged here per DESIGN.md §5(2).
 """
 from __future__ import annotations
 

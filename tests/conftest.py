@@ -1,7 +1,7 @@
 import os
 
-# Smoke tests and benches must see ONE device; only launch/dryrun.py forces
-# 512 host devices (and only in its own process).
+# Tests and benches must see ONE device; a test that needs more forces host
+# devices in a subprocess of its own (tests/test_fleet.py).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 # Tier-1 strictness (DESIGN.md §2.11): silent rank promotion is how weak

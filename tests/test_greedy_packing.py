@@ -15,6 +15,7 @@ from repro.core.fitness import check_schedule
 from repro.core.greedy import SmoothWRR, initial_solution
 from repro.core.types import (CloudConfig, ExecMode, Market, TaskSpec,
                               empty_solution)
+from repro.sim.workloads import make_job
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
@@ -130,6 +131,20 @@ def test_seed_equals_the_repacking_greedy(tasks, market):
     if got is not None:
         c = got.greedy_counters
         assert c.checks == c.kept + c.fallbacks
+
+
+@pytest.mark.parametrize("market", [Market.SPOT, Market.ONDEMAND])
+@pytest.mark.parametrize("name", ["J60", "J80", "J100", "ED200"])
+def test_paper_bag_seed_equals_the_repacking_greedy(name, market):
+    """Each Table III bag on either market: the same plan as Alg. 2 with a
+    full repack per candidate, every check answered from the kept
+    packing (memory never binds on these bags)."""
+    got, want = _both(list(make_job(name).tasks), market)
+    assert got is not None
+    _assert_same_plan(got, want)
+    c = got.greedy_counters
+    assert c.checks == c.kept + c.fallbacks
+    assert c.fallbacks == 0
 
 
 def _j100_bags(n):

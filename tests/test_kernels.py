@@ -7,10 +7,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels.flash_attention.ops import flash_attention
-from repro.kernels.flash_attention.ref import attention_ref
-from repro.kernels.rwkv6.ops import wkv6
-from repro.kernels.rwkv6.ref import wkv6_ref
 from repro.kernels.sched_fitness.mc_step import mc_vm_reduce
 from repro.kernels.sched_fitness.ops import (delta_fitness, insert_tasks,
                                              mc_vm_stats,
@@ -294,97 +290,3 @@ def test_insert_tasks_with_parked_incumbents():
     _insert_vs_oracle(alloc, dest, e, rm, e_new, rm_new, cores, mem,
                       price, spot, **kw)
 
-
-# ---------------------------------------------------------------- flash
-@pytest.mark.parametrize("s,hd,h,hk,window,dtype", [
-    (128, 64, 2, 2, 0, jnp.float32),
-    (256, 128, 4, 2, 0, jnp.float32),
-    (256, 128, 4, 1, 0, jnp.bfloat16),
-    (384, 128, 2, 2, 128, jnp.float32),
-    (130, 64, 2, 2, 0, jnp.float32),       # padding path
-])
-def test_flash_attention_matches_ref(s, hd, h, hk, window, dtype):
-    rng = np.random.default_rng(s + hd)
-    b = 2
-    q = jnp.asarray(rng.normal(0, 1, (b, s, h, hd)), dtype)
-    k = jnp.asarray(rng.normal(0, 1, (b, s, hk, hd)), dtype)
-    v = jnp.asarray(rng.normal(0, 1, (b, s, hk, hd)), dtype)
-    o = flash_attention(q, k, v, qb=64, kb=64, window=window,
-                        interpret=True)
-    rep = h // hk
-    kk = jnp.repeat(k, rep, axis=2).transpose(0, 2, 1, 3).reshape(
-        b * h, s, hd)
-    vv = jnp.repeat(v, rep, axis=2).transpose(0, 2, 1, 3).reshape(
-        b * h, s, hd)
-    qq = q.transpose(0, 2, 1, 3).reshape(b * h, s, hd)
-    want = attention_ref(qq.astype(jnp.float32), kk.astype(jnp.float32),
-                         vv.astype(jnp.float32), window=window)
-    want = want.reshape(b, h, s, hd).transpose(0, 2, 1, 3)
-    tol = 2e-5 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(np.asarray(o, np.float32),
-                               np.asarray(want, np.float32),
-                               rtol=tol, atol=tol)
-
-
-# ---------------------------------------------------------------- rwkv6
-@pytest.mark.parametrize("t,cs,hd,wlow", [
-    (64, 16, 64, 0.85), (96, 32, 128, 0.7), (64, 64, 128, 0.9),
-    (50, 16, 64, 0.8),                      # padding path
-])
-def test_wkv6_matches_ref(t, cs, hd, wlow):
-    rng = np.random.default_rng(t + hd)
-    b, h = 2, 2
-    r = jnp.asarray(rng.normal(0, 1, (b, t, h, hd)), jnp.float32)
-    k = jnp.asarray(rng.normal(0, 1, (b, t, h, hd)), jnp.float32)
-    v = jnp.asarray(rng.normal(0, 1, (b, t, h, hd)), jnp.float32)
-    w = jnp.asarray(rng.uniform(wlow, 0.999, (b, t, h, hd)), jnp.float32)
-    u = jnp.asarray(rng.normal(0, 0.3, (h, hd)), jnp.float32)
-    y, s = wkv6(r, k, v, w, u, cs=cs, interpret=True)
-    rb = r.transpose(0, 2, 1, 3).reshape(b * h, t, hd)
-    kb = k.transpose(0, 2, 1, 3).reshape(b * h, t, hd)
-    vb = v.transpose(0, 2, 1, 3).reshape(b * h, t, hd)
-    wb = w.transpose(0, 2, 1, 3).reshape(b * h, t, hd)
-    ys, ss = [], []
-    for i in range(b * h):
-        yr, sr = wkv6_ref(rb[i:i + 1], kb[i:i + 1], vb[i:i + 1],
-                          wb[i:i + 1], u[i % h])
-        ys.append(yr)
-        ss.append(sr)
-    want_y = jnp.concatenate(ys, 0).reshape(b, h, t, hd).transpose(0, 2, 1, 3)
-    want_s = jnp.concatenate(ss, 0).reshape(b, h, hd, hd)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(want_y),
-                               rtol=1e-4, atol=2e-4)
-    np.testing.assert_allclose(np.asarray(s), np.asarray(want_s),
-                               rtol=1e-4, atol=2e-4)
-
-
-def test_wkv6_state_chains_across_calls():
-    """Final state of chunk kernel == sequential ref state (continuity)."""
-    rng = np.random.default_rng(9)
-    b, t, h, hd = 1, 32, 1, 64
-    mk = lambda: jnp.asarray(rng.normal(0, 1, (b, t, h, hd)), jnp.float32)
-    r, k, v = mk(), mk(), mk()
-    w = jnp.asarray(rng.uniform(0.9, 0.999, (b, t, h, hd)), jnp.float32)
-    u = jnp.zeros((h, hd), jnp.float32)
-    _, s1 = wkv6(r, k, v, w, u, cs=16, interpret=True)
-    _, s2 = wkv6(r, k, v, w, u, cs=32, interpret=True)
-    np.testing.assert_allclose(np.asarray(s1), np.asarray(s2),
-                               rtol=1e-4, atol=1e-4)
-
-
-def test_kernels_match_model_wkv_scan():
-    """The kernel agrees with the model-layer scan (models/rwkv6.py)."""
-    from repro.models.rwkv6 import wkv_scan
-    rng = np.random.default_rng(3)
-    b, t, h, hd = 2, 32, 2, 64
-    mk = lambda: jnp.asarray(rng.normal(0, 1, (b, t, h, hd)), jnp.float32)
-    r, k, v = mk(), mk(), mk()
-    w = jnp.asarray(rng.uniform(0.8, 0.999, (b, t, h, hd)), jnp.float32)
-    u = jnp.asarray(rng.normal(0, 0.3, (h, hd)), jnp.float32)
-    state = jnp.zeros((b, h, hd, hd), jnp.float32)
-    y_model, s_model = wkv_scan(r, k, v, w, u, state)
-    y_kernel, s_kernel = wkv6(r, k, v, w, u, cs=16, interpret=True)
-    np.testing.assert_allclose(np.asarray(y_model), np.asarray(y_kernel),
-                               rtol=1e-4, atol=2e-4)
-    np.testing.assert_allclose(np.asarray(s_model), np.asarray(s_kernel),
-                               rtol=1e-4, atol=2e-4)

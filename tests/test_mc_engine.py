@@ -120,19 +120,54 @@ def test_all_complete_or_violation_flag(j60, plan_bh):
     assert not np.any(late.deadline_met)
 
 
+@pytest.fixture(scope="module")
+def paper_plan(j60, plan_bh):
+    """(job, plan) of a Table III job under a paper policy, each built
+    once per module."""
+    from repro.sim.workloads import make_job
+    policies = {"burst-hads": BURST_HADS, "hads": HADS,
+                "ils-ondemand": ILS_ONDEMAND}
+    cache = {("J60", "burst-hads"): (j60, plan_bh)}
+
+    def get(name, policy):
+        if (name, policy) not in cache:
+            job = make_job(name)
+            cache[name, policy] = (job, build_primary_map(
+                job, CFG, policies[policy], FAST))
+        return cache[name, policy]
+    return get
+
+
 @pytest.mark.parametrize("sc_name", ["none", "sc5"])
-def test_kernel_engine_matches_jnp_engine(j60, plan_bh, sc_name):
+@pytest.mark.parametrize("name,policy", [
+    ("J60", "burst-hads"),
+    # the eval cell's job and its three policies
+    ("ED200", "burst-hads"), ("ED200", "hads"), ("ED200", "ils-ondemand")])
+def test_kernel_engine_matches_jnp_engine(paper_plan, name, policy, sc_name):
     """Pallas-kernel stats path == jnp stats path, including a scenario
     where hibernation events drive migration decisions off the kernel's
     load reduction (both paths score post-progress remaining work)."""
+    job, plan = paper_plan(name, policy)
     base = dict(n_scenarios=8, dt=60.0, seed=0)
-    a = run_mc(j60, plan_bh, CFG, SCENARIOS.get(sc_name, SC_NONE),
+    a = run_mc(job, plan, CFG, SCENARIOS.get(sc_name, SC_NONE),
                MCParams(**base, use_kernel=False))
-    b = run_mc(j60, plan_bh, CFG, SCENARIOS.get(sc_name, SC_NONE),
+    b = run_mc(job, plan, CFG, SCENARIOS.get(sc_name, SC_NONE),
                MCParams(**base, use_kernel=True))
     np.testing.assert_allclose(a.cost, b.cost, rtol=1e-6)
     np.testing.assert_allclose(a.makespan, b.makespan, rtol=1e-6)
     np.testing.assert_array_equal(a.n_hibernations, b.n_hibernations)
+
+
+@pytest.mark.parametrize("name", ["J60", "J80", "J100", "ED200"])
+def test_burst_hads_meets_deadline_under_sc5(paper_plan, name):
+    """The paper's headline claim on the engine, for every Table III job:
+    under sc5 each scenario finishes all tasks and Burst-HADS meets the
+    deadline in at least 90% of them."""
+    job, plan = paper_plan(name, "burst-hads")
+    res = run_mc(job, plan, CFG, SCENARIOS["sc5"],
+                 MCParams(n_scenarios=8, dt=30.0, seed=1))
+    assert np.all(res.unfinished == 0)
+    assert res.deadline_met.mean() >= 0.9, res.makespan
 
 
 def test_scenario_trends(j60, plan_bh, plan_hads):

@@ -32,14 +32,21 @@ def test_no_hibernation_completes(j60):
     assert r.n_hibernations == 0
 
 
+@pytest.fixture(scope="module", params=["J60", "J80", "J100", "ED200"])
+def paper_job(request):
+    """Each Table III job, built once per module."""
+    return make_job(request.param)
+
+
 @pytest.mark.parametrize("sc", ["sc1", "sc2", "sc3", "sc4", "sc5"])
-def test_burst_hads_meets_deadline_all_scenarios(j60, sc):
-    """The paper's headline claim: deadline met even under hibernations."""
+def test_burst_hads_meets_deadline_all_scenarios(paper_job, sc):
+    """The paper's headline claim: deadline met even under hibernations,
+    on every Table III job."""
     for seed in (0, 1):
-        r = simulate(j60, CFG, BURST_HADS, SCENARIOS[sc], seed=seed,
+        r = simulate(paper_job, CFG, BURST_HADS, SCENARIOS[sc], seed=seed,
                      params=FAST)
         assert r.unfinished == 0
-        assert r.deadline_met, (sc, seed, r.makespan)
+        assert r.deadline_met, (paper_job.name, sc, seed, r.makespan)
 
 
 def test_hads_slower_than_burst_hads(j60):

@@ -199,19 +199,23 @@ def test_off_grid_dt(j60, plans):
     assert abs(res.cost.mean() - ref.cost.mean()) < 0.25 * ref.cost.mean()
 
 
-def test_span_kernel_matches_oracle():
+@pytest.mark.parametrize("s,b,v", [(7, 130, 17), (1, 1, 1), (3, 17, 5),
+                                   (8, 60, 27), (16, 200, 35),
+                                   (9, 200, 64)])
+def test_span_kernel_matches_oracle(s, b, v):
     """``mc_span_reduce`` (fused span advance + reductions) against the
-    jnp oracle, including per-scenario span lengths and opt-out tasks."""
+    jnp oracle, including per-scenario span lengths and opt-out tasks.
+    b > one task tile hits accumulation; (16, 200, 35) is ED200 on the
+    35-VM catalog."""
     from repro.kernels.sched_fitness.ops import mc_span_advance
     from repro.kernels.sched_fitness.ref import mc_span_advance_ref
     key = jax.random.PRNGKey(3)
-    s, b, v = 7, 130, 17        # b > one task tile to hit accumulation
     k1, k2, k3, k4 = jax.random.split(key, 4)
     assign = jax.random.randint(k1, (s, b), -1, v)
     rem = jax.random.uniform(k2, (s, b)) * 50.0
     rem = rem * (jax.random.uniform(k3, (s, b)) > 0.2)
     drem = jax.random.uniform(k4, (s, b)) * 0.5
-    m = jax.numpy.asarray([0., 1., 3., 10., 40., 2., 7.])
+    m = jax.numpy.asarray(np.resize([0., 1., 3., 10., 40., 2., 7.], s))
     got = mc_span_advance(assign, rem, drem, m, v=v)
     want = mc_span_advance_ref(
         assign, rem, jax.numpy.where(rem > 0, drem, 0.0), m, v)
